@@ -8,6 +8,7 @@ of one compile + one serial scan per point.
 """
 from __future__ import annotations
 
+import os
 import sys
 import time
 from typing import Dict, List, Optional
@@ -20,6 +21,20 @@ from repro.core import (Flows, FlowSchedule, LeafSpine, SimConfig,
                         simulate, simulate_batch, simulate_slots_batch,
                         stack_flow_schedules, stack_flows)
 from repro.core.sweep import tree_index as _tree_index
+
+def use_compile_cache() -> str:
+    """Put JAX's persistent compilation cache where
+    ``$JAX_COMPILATION_CACHE_DIR`` says (JAX reads the variable itself),
+    else at the fixed ``<repo>/.jax_cache``: the path is part of the
+    cache key, so it never moves. Entry points call this; importing
+    ``repro`` sets no cache."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 SHORT = 10e3            # <10 KB   (paper Fig. 6 buckets)
 MEDIUM_LO = 100e3

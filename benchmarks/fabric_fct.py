@@ -204,6 +204,35 @@ def fabric16_scenario(load: float = 0.6, duration: float = 0.085,
     return ft, make_schedule(fl)
 
 
+def fabric16_impairments(ft):
+    """The headline's degraded spine: AGG<->CORE capacity flaps between
+    40G and line rate twice a millisecond; every other link sees 0.2%
+    random loss."""
+    deg = LinkProcess(kind="oscillate", bw_lo=40e9, period=500e-6, seed=7)
+    return fabric_impairments(ft, rules={(AGG, CORE): deg, (CORE, AGG): deg},
+                              default=netem(loss=0.002, jitter=0.0, seed=13))
+
+
+def anchor256():
+    """The sharded engine's exactness anchor on the 256-host leaf-spine
+    (the fig6 paper fabric): ``(topo, sched, slots, law_cfg, cfg,
+    impair)`` with the mixed impairment regime (oscillating edge
+    capacity + stochastic loss + jitter)."""
+    ls = compile_routes(leaf_spine_fabric(racks=8, hosts_per_rack=32,
+                                          spines=2))
+    sched = make_schedule(poisson_websearch(ls, 0.3, 0.0012, DT, seed=11))
+    S = -(-suggest_slots(sched, DT) // 8) * 8
+    cfg = SimConfig(dt=DT, steps=3000, hist=512, update_period=2e-6)
+    sp = CircuitSchedule(day=50 * US, night=10 * US, matchings=4).params()
+    lcfg = default_law_config(schedule_as_flows(sched), expected_flows=8.0,
+                              sched=sp)
+    imp = fabric_impairments(
+        ls, rules={(TOR, HOST): LinkProcess(kind="oscillate", bw_lo=2.5e9,
+                                            period=200e-6, seed=5)},
+        default=netem(loss=0.01, jitter=1e-6, seed=9))
+    return ls.topology(), sched, S, lcfg, cfg, imp
+
+
 def _fabric16_anchor_bitmatch(devices):
     """Sharded == reference slot engine, bit for bit, for EVERY law in
     the registry — feedback-channel laws (pause, incast, hop-local)
@@ -215,19 +244,7 @@ def _fabric16_anchor_bitmatch(devices):
     channel-covering law subset under the mixed impairment regime
     (oscillating edge capacity + stochastic loss + jitter) and returns
     its verdict separately: (clean_ok, impaired_ok)."""
-    ls = compile_routes(leaf_spine_fabric(racks=8, hosts_per_rack=32,
-                                          spines=2))
-    sched = make_schedule(poisson_websearch(ls, 0.3, 0.0012, DT, seed=11))
-    S = -(-suggest_slots(sched, DT) // 8) * 8
-    cfg = SimConfig(dt=DT, steps=3000, hist=512, update_period=2e-6)
-    topo = ls.topology()
-    sp = CircuitSchedule(day=50 * US, night=10 * US, matchings=4).params()
-    lcfg = default_law_config(schedule_as_flows(sched), expected_flows=8.0,
-                              sched=sp)
-    imp = fabric_impairments(
-        ls, rules={(TOR, HOST): LinkProcess(kind="oscillate", bw_lo=2.5e9,
-                                            period=200e-6, seed=5)},
-        default=netem(loss=0.01, jitter=1e-6, seed=9))
+    topo, sched, S, lcfg, cfg, imp = anchor256()
 
     def _same(law, **kw):
         st_r, rec_r = simulate_slots(topo, sched, law, S, lcfg, cfg, **kw)
@@ -297,11 +314,7 @@ def smoke_fabric16(devices=None) -> dict:
     cfg = SimConfig(dt=DT, steps=steps, hist=512, update_period=2e-6)
     lcfg = default_law_config(schedule_as_flows(sched), expected_flows=8.0)
     topo = ft.topology()
-    # degraded spine: AGG<->CORE capacity flaps between 40G and line
-    # rate twice a millisecond; everything else sees 0.2% random loss
-    deg = LinkProcess(kind="oscillate", bw_lo=40e9, period=500e-6, seed=7)
-    imp = fabric_impairments(ft, rules={(AGG, CORE): deg, (CORE, AGG): deg},
-                             default=netem(loss=0.002, jitter=0.0, seed=13))
+    imp = fabric16_impairments(ft)
 
     t0 = time.time()
     st_n, _ = simulate_slots_sharded(topo, sched, "powertcp", S, lcfg, cfg,
@@ -332,7 +345,7 @@ def smoke_fabric16(devices=None) -> dict:
         "fct_fabric16_steps": steps,
         "fct_fabric16_chunk": chunk,
         "fct_fabric16_devices": ndev,
-        "fct_fabric16_devices_avail": ndev,
+        "fct_fabric16_devices_avail": jax.local_device_count(),
         "fct_fabric16_impaired": True,
         "fct_fabric16_wall_s": round(wall_n, 3),
         "fct_fabric16_wall_1dev_s": round(wall_1, 3),

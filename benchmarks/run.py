@@ -12,8 +12,10 @@ consistency errors) to the repo root — the perf trajectory anchor for
 scaling PRs (see benchmarks/README.md for the field reference).
 ``--devices N|auto`` additionally runs the sweep with the batch axis
 sharded across devices (``simulate_batch(devices=...)``, DESIGN.md
-section 11) and records the sharded points/sec; on a single-device host
-it falls back to the vmap path and reports ``devices: 1``. The slot leg
+section 11) and records the sharded points/sec; ``auto`` on a
+single-device host falls back to the vmap path and reports
+``devices: 1``, while an explicit N above the local device count
+raises. The slot leg
 also runs the whole-tick megakernel backend on the identical workload
 (``fct_mega_*`` fields: wall time, speedup over the reference slot
 stream, the anchor bit-exactness gate and paper-scale consistency —
@@ -442,6 +444,8 @@ def main():
                  "PYTHONPATH": os.path.join(root, "src") + os.pathsep +
                  os.environ.get("PYTHONPATH", "")})
 
+    from .common import use_compile_cache
+    use_compile_cache()
     if a.smoke:
         data = run_smoke(devices=devices)
         return 0 if smoke_ok(data) else 1

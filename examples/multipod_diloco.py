@@ -32,6 +32,7 @@ from repro.commsched import (ControllerConfig, DCNConfig, make_controller,
                              make_outer_sync, rdcn_bw_fn, run_reduction,
                              window_to_buckets)
 from repro.configs import TrainConfig, reduced_config
+from repro.launch import make_mesh
 from repro.models import init_params, lm_specs, num_bytes
 from repro.sharding import tree_shardings
 from repro.train import DataConfig, SyntheticData, init_opt, make_train_step
@@ -43,7 +44,7 @@ def main():
     ap.add_argument("--inner", type=int, default=5)
     a = ap.parse_args()
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = reduced_config("qwen3_14b")
     tcfg = TrainConfig(microbatch=1, remat="none", lr=5e-3, warmup_steps=5,
                        total_steps=200)

@@ -25,9 +25,8 @@ from typing import List, Tuple
 import jax
 import jax.numpy as jnp
 
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..sharding.compat import shard_map
 
 
 # -------------------------------------------------------------------------

@@ -7,7 +7,11 @@ registry (``laws.LAW_BACKENDS``) is the single source of dispatch truth.
 
 Backend contract (DESIGN.md section 10): a fused ``update`` consumes the
 same ``PathObs``/state pytree as its reference twin and must be numerically
-equivalent (the tier-1 suite asserts full-trajectory agreement). The only
+equivalent (the tier-1 suite asserts full-trajectory agreement). The fused
+backend is close, not exact: FCTs within rtol 1e-4 and atol 2e-6 s of the
+reference (``tests/test_backends.py``; ``chip_smoke.py`` gates the same
+closeness on the chip), because the incidence matmul reassociates each
+queue's arrival sum. The only
 extra constraint is that EWMA ``gamma`` must be a concrete Python float —
 the kernels take it as a static compile-time argument, so a fused law
 cannot sit under a vmapped gamma sweep (use the reference backend there).

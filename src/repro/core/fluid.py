@@ -64,12 +64,13 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from ..kernels.queue_arrivals import (apply_loss, ordered_scatter_add,
                                       queue_arrivals, suggest_maxdeg,
                                       update_incidence)
+from ..launch.mesh import make_mesh
 from ..sharding.axes import active_mesh, active_rules, axes_to_pspec
-from ..sharding.compat import shard_map
 from .faults import FaultSpec, InjectedCrash, UnsupportedFeature
 from .impair import ImpairmentParams, impair_vectors, link_bw_at
 from .laws import Law, LawConfig, get_law, _nofma, _pin
@@ -1491,13 +1492,19 @@ def resolve_devices(devices) -> int:
     """Normalize the ``devices`` argument of ``simulate_batch``.
 
     ``None``/``0``/``1`` -> 1 (single-device vmap path); ``"auto"`` -> all
-    local devices; an int is clamped to what is actually present, so specs
-    written for an 8-device host degrade gracefully on a laptop.
+    local devices; an explicit count larger than the local device count
+    raises — a run asked for N devices never quietly runs on fewer.
     """
     if devices is None:
         return 1
-    n = jax.local_device_count() if devices == "auto" else int(devices)
-    return max(1, min(n, jax.local_device_count()))
+    avail = jax.local_device_count()
+    if devices == "auto":
+        return avail
+    n = int(devices)
+    if n > avail:
+        raise ValueError(f"devices={n} requested but only {avail} local "
+                         f"device(s) are present")
+    return max(1, n)
 
 
 def _batch_mesh(ndev: int):
@@ -1508,7 +1515,7 @@ def _batch_mesh(ndev: int):
     mesh = active_mesh()
     if mesh is not None:
         return mesh, active_rules()
-    return jax.make_mesh((ndev,), ("data",)), None
+    return make_mesh((ndev,), ("data",)), None
 
 
 def _pad_batch(tree, pad: int):

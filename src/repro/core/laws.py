@@ -67,24 +67,6 @@ def _nofma(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.maximum(x, jnp.float32(-3e38))
 
 
-def _register_barrier_batcher():
-    """jax 0.4.37 ships no vmap rule for ``optimization_barrier`` — the
-    barrier is an identity, so batching is trivial (bind the batched args,
-    keep their batch dims). Without this the batched engines
-    (``simulate_batch``/``simulate_slots_batch``) could not contain pins."""
-    try:
-        from jax._src.lax.lax import optimization_barrier_p
-        from jax.interpreters import batching
-    except ImportError:                                  # pragma: no cover
-        return
-    if optimization_barrier_p not in batching.primitive_batchers:
-        batching.primitive_batchers[optimization_barrier_p] = (
-            lambda args, dims: (optimization_barrier_p.bind(*args), dims))
-
-
-_register_barrier_batcher()
-
-
 class LawConfig(NamedTuple):
     """Law hyperparameters. Every field is either a scalar, a per-flow [F]
     vector, or a pytree of scalars — so a whole config batches under

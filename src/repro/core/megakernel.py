@@ -37,16 +37,16 @@ rebuilds the whole tick around one fused core:
 
 Two lowerings run the same tick function:
 
-  * **XLA scan** (default off-TPU): the tick scans flat through
-    ``fluid._scan_scenario`` exactly like the reference engine (same
-    ``record_every`` chunking), so the only differences against the
-    reference program are the restructurings above;
-  * **Pallas whole-tick kernel** (``kernels.fused_tick``, default on
-    TPU): one kernel invocation advances a K-tick block with every state
-    leaf — pool vectors, queue vector, law pytree, ring buffers, FCT
-    output — resident in VMEM across an inner ``fori_loop``, emitting
-    only chunked recording rows and the final state. Tested in interpret
-    mode off-TPU.
+  * **XLA scan** (the default on every platform, TPU included): the tick
+    scans flat through ``fluid._scan_scenario`` exactly like the
+    reference engine (same ``record_every`` chunking), so the only
+    differences against the reference program are the restructurings
+    above;
+  * **Pallas whole-tick harness** (``kernels.fused_tick``,
+    ``impl="pallas"``): one ``pallas_call`` evaluates a K-tick block.
+    Mosaic cannot lower the tick's gathers, scatter and
+    ``dynamic_slice``, so it runs only in interpret mode off-TPU (tests);
+    on TPU ``impl="pallas"`` raises before compiling.
 
 Exactness contract (the PR-3 anchor discipline, tests/test_megakernel.py,
 CI-gated via ``fct_mega_exact_bitmatch``): on the single-bottleneck
@@ -592,9 +592,11 @@ def make_block_fn(tick: Callable, record: bool,
 
 
 def default_impl() -> str:
-    """Lowering choice: the Pallas whole-tick kernel on TPU, the flat XLA
-    scan elsewhere (Pallas off-TPU would run interpreted)."""
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    """Lowering choice: the flat XLA scan on every platform. The Pallas
+    whole-tick harness (``kernels.fused_tick``) cannot lower for TPU —
+    Mosaic has no lowering for the tick's gathers, scatter and
+    ``dynamic_slice`` — so off-TPU it only runs interpreted."""
+    return "xla"
 
 
 def _unpack_state(carry: MegaCarry, N: int, Q1: int) -> SlotState:
@@ -621,13 +623,18 @@ def simulate_slots_mega(sim, bw_fn=None, record: bool = True,
     control the idle-tick conds (see ``make_tick`` — the batched vmap
     entry disables them).
     """
+    impl = impl or default_impl()
+    if impl == "pallas" and jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "impl='pallas' (the whole-tick Pallas harness) cannot compile "
+            "for TPU: Mosaic has no lowering for the tick's gathers, "
+            "scatter and dynamic_slice. Use impl='xla' (the default).")
     cfg = sim.cfg
     T = int(cfg.steps)
     re = max(int(cfg.record_every), 1) if record else 1
     if record and re > 1 and T % re:
         raise ValueError(f"steps ({T}) must be divisible by "
                          f"record_every ({re})")
-    impl = impl or default_impl()
     gate = True if gate is None else gate
     tick = make_tick(sim, bw_fn, gate=gate, quiet=quiet)
     N = fluid._slot_n(sim)

@@ -88,13 +88,14 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.queue_arrivals import (apply_loss, csr_gather_arrivals,
                                       ordered_scatter_add, seg_ranks,
                                       stable_sort_ids, suggest_maxdeg)
+from ..launch.mesh import make_mesh
 from ..sharding.axes import axes_to_pspec
-from ..sharding.compat import shard_map
 from .fluid import (_CHUNK_SEG_MAX, _INT32_MAX, _bandwidth, _buffer_caps,
                     _check_impair, _gather_law_cfg, _hop_keep, _hop_sum,
                     _host_window, _incast_count, _marking, _pause_step,
@@ -865,6 +866,56 @@ def comm_census(mi: ShardInfo, S: int, H: int, Q: int,
     }
 
 
+def _sharded_programs(sim: SlotSim, mi: ShardInfo, mesh, bw_fn,
+                      record: bool):
+    """(init, get_seg) of a sharded run on ``mesh``: ``init(win, w0)``
+    builds the sharded carry from the first schedule window, and
+    ``get_seg(L)`` is the jitted program advancing that carry ``L`` ticks
+    against a window (memoized per ``L``). Split from
+    ``simulate_slots_sharded`` so a mesh of described devices can compile
+    the same programs (tests/test_tpu_compile.py)."""
+    N = int(sim.sched.start.shape[0])
+    law, law_cfg = sim.law, sim.law_cfg
+    law_template = jax.eval_shape(
+        lambda: law.init(1, _gather_law_cfg(
+            law_cfg, jnp.zeros((1,), jnp.int32), N)))
+    cspecs = _carry_specs(mesh, law_template, law, mi.use_csr)
+    rep = P()
+
+    def init_fn(win, w0):
+        simw = sim._replace(sched=win, n_flows=N, win_off=w0)
+        carry = _init_carry(simw, mi)
+        audit_carry_dtypes(carry)
+        return carry
+
+    init_j = jax.jit(shard_map(init_fn, mesh=mesh, in_specs=(rep, rep),
+                               out_specs=cspecs, check_vma=False))
+    seg_cache = {}
+
+    def get_seg(L):
+        if L in seg_cache:
+            return seg_cache[L]
+
+        def seg_fn(carry, win, w0):
+            simw = sim._replace(sched=win, n_flows=N, win_off=w0)
+            ax = jax.lax.axis_index(_AX)
+            off = ax * mi.Sl
+            blk0 = ax * mi.Qb
+
+            def body(c, _):
+                return _shard_tick(simw, mi, off, blk0, c, bw_fn, record)
+
+            return jax.lax.scan(body, carry, None, length=L)
+
+        f = jax.jit(shard_map(seg_fn, mesh=mesh,
+                              in_specs=(cspecs, rep, rep),
+                              out_specs=(cspecs, rep), check_vma=False))
+        seg_cache[L] = f
+        return f
+
+    return init_j, get_seg
+
+
 def simulate_slots_sharded(topo: Topology, sched: FlowSchedule,
                            law_name: Union[str, Law], slots: int,
                            law_cfg: Optional[LawConfig] = None,
@@ -918,45 +969,8 @@ def simulate_slots_sharded(topo: Topology, sched: FlowSchedule,
     C = N if chunk is None else min(max(int(chunk), S), max(N, 1))
     start_np = np.asarray(sched_np.start, np.float32)
 
-    mesh = jax.make_mesh((ndev,), (_AX,))
-    law_template = jax.eval_shape(
-        lambda: law.init(1, _gather_law_cfg(
-            law_cfg, jnp.zeros((1,), jnp.int32), N)))
-    cspecs = _carry_specs(mesh, law_template, law, mi.use_csr)
-    rep = P()
-
-    def init_fn(win, w0):
-        simw = sim._replace(sched=win, n_flows=N, win_off=w0)
-        carry = _init_carry(simw, mi)
-        audit_carry_dtypes(carry)
-        return carry
-
-    init_j = jax.jit(shard_map(init_fn, mesh=mesh, in_specs=(rep, rep),
-                               out_specs=cspecs, check_vma=False))
-
-    seg_cache = {}
-
-    def get_seg(L):
-        if L in seg_cache:
-            return seg_cache[L]
-
-        def seg_fn(carry, win, w0):
-            simw = sim._replace(sched=win, n_flows=N, win_off=w0)
-            ax = jax.lax.axis_index(_AX)
-            off = ax * mi.Sl
-            blk0 = ax * mi.Qb
-
-            def body(c, _):
-                return _shard_tick(simw, mi, off, blk0, c, bw_fn, record)
-
-            return jax.lax.scan(body, carry, None, length=L)
-
-        f = jax.jit(shard_map(seg_fn, mesh=mesh,
-                              in_specs=(cspecs, rep, rep),
-                              out_specs=(cspecs, rep), check_vma=False))
-        seg_cache[L] = f
-        return f
-
+    mesh = make_mesh((ndev,), (_AX,))
+    init_j, get_seg = _sharded_programs(sim, mi, mesh, bw_fn, record)
     carry = init_j(_host_window(sched_np, 0, C, Q),
                    jnp.asarray(0, jnp.int32))
     recs = []

@@ -24,17 +24,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams (~0.5); support both.
-def _no_compiler_params(*_a, **_k):
-    raise RuntimeError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; unsupported jax version")
-
-
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams",
-                                  _no_compiler_params))
-
 NEG_INF = -1e30
 
 
@@ -132,7 +121,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, bq=128, bk=128,
             pltpu.VMEM((bq_,), jnp.float32),        # running denominator
             pltpu.VMEM((bq_, D), jnp.float32),      # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

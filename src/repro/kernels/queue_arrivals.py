@@ -5,13 +5,16 @@ arrival sums (``zeros.at[path].add(lam)``). Two accelerated forms exist:
 
 Dense (``queue_arrivals``, the ``"fused"`` backend): scatters serialize
 badly on TPU; the TPU-native adaptation (DESIGN.md section 2) is a dense
-incidence form: per hop h, ``arr += lam_del[h] @ onehot[h]`` — an
-[1,F] x [F,Q] matmul on the MXU — followed by the fused elementwise queue
-integration ``q' = clip(q + (arr - out) dt, 0, caps)``. Grid tiles the
-queue axis; all H hops accumulate within one grid step, so arrivals and
-the queue update leave VMEM exactly once. The matmul REASSOCIATES each
-queue's sum, so the dense form is numerically close to (not bitwise equal
-with) the reference scatter.
+incidence form: the hops fold into the contraction axis, so the arrivals
+are ONE ``[1, H*F] x [H*F, Q]`` matmul on the MXU, followed by the fused
+elementwise queue integration ``q' = clip(q + (arr - out) dt, 0, caps)``.
+The grid tiles the queue axis (parallel) and the contraction axis
+(accumulated in the resident output block), with 128-multiple blocks
+sized from a VMEM budget, so the 256-host leaf-spine (288 queues) and
+the k=16 fat-tree (5,120 queues) both compile for TPU at any flow count.
+The matmul runs at full f32 precision but REASSOCIATES each queue's sum,
+so the dense form is numerically close to (not bitwise equal with) the
+reference scatter.
 
 Sparse (``queue_arrivals_sparse``, the ``"megakernel"`` backend,
 DESIGN.md section 13): the incidence of a slot pool is tiny
@@ -295,51 +298,70 @@ def update_incidence(incidence: jnp.ndarray, path: jnp.ndarray,
     return jnp.where(changed[None, :, None], cols, incidence)
 
 
+LANES = 128
+ONEHOT_BLOCK_ELEMS = 1 << 19      # 2 MiB f32 incidence block per grid step
+MAX_QUEUE_BLOCK = 512
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def incidence_tiling(K: int, Q: int):
+    """(bk, Kp, bq, Qp): contraction and queue block sizes of the dense
+    kernel (128-multiples) and the padded extents they divide."""
+    bq = min(_round_up(Q, LANES), MAX_QUEUE_BLOCK)
+    bk = min(_round_up(K, LANES),
+             max(LANES, ONEHOT_BLOCK_ELEMS // bq // LANES * LANES))
+    return bk, _round_up(K, bk), bq, _round_up(Q, bq)
+
+
 def _kernel(lam_ref, onehot_ref, q_ref, out_ref, caps_ref, arr_ref,
-            qnew_ref, *, dt, hops):
-    acc = jnp.zeros((1, arr_ref.shape[-1]), jnp.float32)
-    for h in range(hops):
-        lam = lam_ref[h][None, :]                    # [1, F]
-        m = onehot_ref[h]                            # [F, BQ]
-        acc = acc + jax.lax.dot(lam, m, preferred_element_type=jnp.float32)
-    arr = acc[0]
-    arr_ref[...] = arr
-    qnew_ref[...] = jnp.clip(q_ref[...] + (arr - out_ref[...]) * dt,
-                             0.0, caps_ref[...])
+            qnew_ref, *, dt):
+    k = pl.program_id(1)
+
+    @pl.when(k == 0)
+    def _init():
+        arr_ref[...] = jnp.zeros_like(arr_ref)
+
+    arr_ref[...] += jax.lax.dot(lam_ref[...], onehot_ref[...],
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=jnp.float32)
+
+    @pl.when(k == pl.num_programs(1) - 1)
+    def _integrate():
+        qnew_ref[...] = jnp.clip(
+            q_ref[...] + (arr_ref[...] - out_ref[...]) * dt,
+            0.0, caps_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("dt", "bq", "interpret"))
-def queue_arrivals(lam_del, onehot, q, out_rate, caps, *, dt, bq=128,
+@functools.partial(jax.jit, static_argnames=("dt", "interpret"))
+def queue_arrivals(lam_del, onehot, q, out_rate, caps, *, dt,
                    interpret=None):
     """lam_del: [H,F]; onehot: [H,F,Q]; q/out_rate/caps: [Q] ->
     (arrivals [Q], q_new [Q])."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     H, F, Q = onehot.shape
-    bq_ = min(bq, Q)
-    pad = (-Q) % bq_
-    if pad:
-        onehot = jnp.pad(onehot, ((0, 0), (0, 0), (0, pad)))
-        q = jnp.pad(q, (0, pad))
-        out_rate = jnp.pad(out_rate, (0, pad))
-        caps = jnp.pad(caps, (0, pad))
-    Qp = Q + pad
+    K = H * F
+    bk, Kp, bq, Qp = incidence_tiling(K, Q)
+    lam = jnp.pad(lam_del.astype(jnp.float32).reshape(1, K),
+                  ((0, 0), (0, Kp - K)))
+    inc = jnp.pad(onehot.astype(jnp.float32).reshape(K, Q),
+                  ((0, Kp - K), (0, Qp - Q)))
+    row = lambda x: jnp.pad(x.astype(jnp.float32), (0, Qp - Q)).reshape(1, Qp)
+    qspec = pl.BlockSpec((1, bq), lambda j, k: (0, j))
     arr, qnew = pl.pallas_call(
-        functools.partial(_kernel, dt=dt, hops=H),
-        grid=(Qp // bq_,),
+        functools.partial(_kernel, dt=dt),
+        grid=(Qp // bq, Kp // bk),
         in_specs=[
-            pl.BlockSpec((H, F), lambda i: (0, 0)),
-            pl.BlockSpec((H, F, bq_), lambda i: (0, 0, i)),
-            pl.BlockSpec((bq_,), lambda i: (i,)),
-            pl.BlockSpec((bq_,), lambda i: (i,)),
-            pl.BlockSpec((bq_,), lambda i: (i,)),
+            pl.BlockSpec((1, bk), lambda j, k: (0, k)),
+            pl.BlockSpec((bk, bq), lambda j, k: (k, j)),
+            qspec, qspec, qspec,
         ],
-        out_specs=(pl.BlockSpec((bq_,), lambda i: (i,)),
-                   pl.BlockSpec((bq_,), lambda i: (i,))),
-        out_shape=(jax.ShapeDtypeStruct((Qp,), jnp.float32),
-                   jax.ShapeDtypeStruct((Qp,), jnp.float32)),
+        out_specs=(qspec, qspec),
+        out_shape=(jax.ShapeDtypeStruct((1, Qp), jnp.float32),
+                   jax.ShapeDtypeStruct((1, Qp), jnp.float32)),
         interpret=interpret,
-    )(lam_del.astype(jnp.float32), onehot.astype(jnp.float32),
-      q.astype(jnp.float32), out_rate.astype(jnp.float32),
-      caps.astype(jnp.float32))
-    return arr[:Q], qnew[:Q]
+    )(lam, inc, row(q), row(out_rate), row(caps))
+    return arr[0, :Q], qnew[0, :Q]

@@ -9,6 +9,7 @@ Hardware model used across roofline/benchmarks (per the brief):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
 HBM_BW = 819e9               # bytes/s per chip
@@ -20,9 +21,12 @@ HBM_BYTES = 16 * 2**30       # per chip
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (reduced meshes for tests, elasticity experiments)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (reduced meshes for tests, elasticity experiments).
+    Axes are ``Auto``: shardings come from the logical-axis rules and
+    ``with_sharding_constraint``, not from the types of the arrays."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))

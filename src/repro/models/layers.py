@@ -17,9 +17,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 
 from ..sharding.axes import active_mesh, constrain
-from ..sharding.compat import shard_map
 from .spec import ParamSpec, fan_in_normal
 
 from jax.sharding import PartitionSpec as P

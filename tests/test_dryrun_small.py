@@ -12,12 +12,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json
 sys.path.insert(0, {src!r})
-import jax
 from repro.configs import reduced_config, ShapeConfig, TrainConfig
 from repro.launch.dryrun import build_cell
 from repro.launch.hlo_analysis import analyze_hlo
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 out = {{}}
 tcfg = TrainConfig(microbatch=2, remat="full")
 for arch in ["qwen3_moe_30b_a3b", "recurrentgemma_2b"]:

@@ -103,7 +103,8 @@ def _powertcp_inputs(F, H):
                 beta=jnp.full((F,), 25e3, jnp.float32))
 
 
-@pytest.mark.parametrize("F,H", [(64, 1), (300, 3), (1000, 2), (17, 4)])
+@pytest.mark.parametrize("F,H", [(64, 1), (300, 3), (1000, 2), (17, 4),
+                                 (40000, 2)])  # last: several grid steps
 def test_powertcp_step(F, H):
     kw = _powertcp_inputs(F, H)
     wk, gk = powertcp_step(**kw, interpret=True)
@@ -175,7 +176,8 @@ def test_theta_powertcp_step_matches_law():
 # -------------------------------------------------------------------------
 
 @pytest.mark.parametrize("H,F,Q", [(1, 32, 16), (3, 128, 100), (2, 50, 7),
-                                   (4, 256, 300)])
+                                   (4, 256, 300),
+                                   (3, 900, 700)])  # several blocks each way
 def test_queue_arrivals(H, F, Q):
     lam = jnp.abs(_randn((H, F)))
     path = RNG.integers(0, Q, (H, F))
