@@ -51,6 +51,7 @@ from typing import Dict, List, Optional, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from . import obs
 from .types import Flows, Topology, GBPS, US
 
 HOST, TOR, AGG, CORE = 0, 1, 2, 3      # conventional tier labels
@@ -602,26 +603,29 @@ class FabricRoutes:
         argument (the RNG spine pick is superseded by the hash).
         """
         n = len(src)
-        path, tf, rtt, _ = self.select(src, dst, flow_ids, seed)
-        nic = self._nic[np.asarray(src, np.int64)]
-        if (nic <= 0).any():
-            raise ValueError("a flow sources at a host with no egress link")
-        if weights is None:
-            weights = np.ones(n)
-        stops_a = (np.full((n,), np.inf, np.float32) if stops is None
-                   else np.asarray(stops, np.float32))
-        return Flows(
-            path=jnp.asarray(path),
-            tf_steps=jnp.asarray(np.round(tf / sim_dt).astype(np.int32)),
-            rtt_steps=jnp.asarray(
-                np.maximum(np.round(rtt / sim_dt), 1).astype(np.int32)),
-            tau=jnp.asarray(rtt.astype(np.float32)),
-            nic_rate=jnp.asarray(nic.astype(np.float32)),
-            size=jnp.asarray(np.asarray(sizes), jnp.float32),
-            start=jnp.asarray(np.asarray(starts), jnp.float32),
-            stop=jnp.asarray(stops_a),
-            weight=jnp.asarray(np.asarray(weights), jnp.float32),
-        )
+        with obs.span("schedule.route", flows=n):
+            path, tf, rtt, _ = self.select(src, dst, flow_ids, seed)
+            nic = self._nic[np.asarray(src, np.int64)]
+            if (nic <= 0).any():
+                raise ValueError(
+                    "a flow sources at a host with no egress link")
+            if weights is None:
+                weights = np.ones(n)
+            stops_a = (np.full((n,), np.inf, np.float32) if stops is None
+                       else np.asarray(stops, np.float32))
+            return Flows(
+                path=jnp.asarray(path),
+                tf_steps=jnp.asarray(
+                    np.round(tf / sim_dt).astype(np.int32)),
+                rtt_steps=jnp.asarray(
+                    np.maximum(np.round(rtt / sim_dt), 1).astype(np.int32)),
+                tau=jnp.asarray(rtt.astype(np.float32)),
+                nic_rate=jnp.asarray(nic.astype(np.float32)),
+                size=jnp.asarray(np.asarray(sizes), jnp.float32),
+                start=jnp.asarray(np.asarray(starts), jnp.float32),
+                stop=jnp.asarray(stops_a),
+                weight=jnp.asarray(np.asarray(weights), jnp.float32),
+            )
 
     # -- workload-facing conveniences (the fabric protocol shared with the
     #    LeafSpine facade; see workload.py) --------------------------------

@@ -71,6 +71,7 @@ from ..kernels.queue_arrivals import (apply_loss, ordered_scatter_add,
                                       update_incidence)
 from ..launch.mesh import make_mesh
 from ..sharding.axes import active_mesh, active_rules, axes_to_pspec
+from . import obs
 from .faults import FaultSpec, InjectedCrash, UnsupportedFeature
 from .impair import ImpairmentParams, impair_vectors, link_bw_at
 from .laws import Law, LawConfig, get_law, _nofma, _pin
@@ -873,135 +874,144 @@ def slot_step(sim: SlotSim, state: SlotState, bw_fn=None, alloc_fn=None):
     N = _slot_n(sim)
     D = cfg.hist
     dt = cfg.dt
-    t_sec = _nofma(state.t.astype(jnp.float32) * dt)   # mirror of step()
-    ptr = jnp.mod(state.t, D)
-    bw = _bandwidth(topo, bw_fn, t_sec, sim.impair)           # [Q+1]
-    keep, jit = (impair_vectors(t_sec, sim.impair)
-                 if sim.impair is not None else (None, None))
-    sidx = jnp.arange(S)
+    with jax.named_scope("rates"):
+        t_sec = _nofma(state.t.astype(jnp.float32) * dt)   # mirror of step()
+        ptr = jnp.mod(state.t, D)
+        bw = _bandwidth(topo, bw_fn, t_sec, sim.impair)           # [Q+1]
+        keep, jit = (impair_vectors(t_sec, sim.impair)
+                     if sim.impair is not None else (None, None))
+        sidx = jnp.arange(S)
 
     # -- admit / retire ----------------------------------------------------
-    state, occupied = _admit_retire(sim, state, t_sec)
-    (path, tf_steps, tau, nic) = (state.path, state.tf_steps, state.tau,
-                                  state.nic_rate)
-    gf = jnp.clip(state.slot_flow, 0, N - 1)
-    cfg_slot = _gather_law_cfg(sim.law_cfg, gf, N)
+    with jax.named_scope("admit"):
+        state, occupied = _admit_retire(sim, state, t_sec)
+        (path, tf_steps, tau, nic) = (state.path, state.tf_steps, state.tau,
+                                      state.nic_rate)
+        gf = jnp.clip(state.slot_flow, 0, N - 1)
+        cfg_slot = _gather_law_cfg(sim.law_cfg, gf, N)
 
-    active = (occupied & (t_sec >= state.start) & (state.remaining > 0.0) &
-              (t_sec < state.stop))
-    # -- instantaneous RTT and send rates ---------------------------------
-    q_hop = state.q[path]                                     # [S,H]
-    b_hop = _pin(bw[path])            # mirror of the padded engine's pin
-    valid = path < topo.num_queues
-    qb_now = q_hop / b_hop
-    if jit is not None:
-        qb_now = qb_now + jit[path]
-    theta_now = tau + _hop_sum(
-        jnp.where(valid, qb_now, 0.0))
-    lam = jnp.where(active,
-                    jnp.minimum(jnp.minimum(_pin(state.w / theta_now),
-                                            state.rate_cap),
-                                nic), 0.0)
+    with jax.named_scope("rates"):
+        active = (occupied & (t_sec >= state.start) & (state.remaining > 0.0) &
+                  (t_sec < state.stop))
+        # -- instantaneous RTT and send rates -----------------------------
+        q_hop = state.q[path]                                     # [S,H]
+        b_hop = _pin(bw[path])            # mirror of the padded engine's pin
+        valid = path < topo.num_queues
+        qb_now = q_hop / b_hop
+        if jit is not None:
+            qb_now = qb_now + jit[path]
+        theta_now = tau + _hop_sum(
+            jnp.where(valid, qb_now, 0.0))
+        lam = jnp.where(active,
+                        jnp.minimum(jnp.minimum(_pin(state.w / theta_now),
+                                                state.rate_cap),
+                                    nic), 0.0)
 
-    # -- histories at current time ----------------------------------------
-    hist_lam = state.hist_lam.at[ptr].set(lam)
-    hist_w = state.hist_w.at[ptr].set(state.w)
+        # -- histories at current time ------------------------------------
+        hist_lam = state.hist_lam.at[ptr].set(lam)
+        hist_w = state.hist_w.at[ptr].set(state.w)
 
     # -- queue update (reads older than admission are the prior occupant's
     #    — they are exactly 0 by the free_at drain guarantee, and the mask
     #    also reproduces the padded engine's all-zero pre-start history) --
-    hop_delay_idx = jnp.mod(ptr - tf_steps, D)                # [S,H]
-    lam_del = hist_lam[hop_delay_idx, sidx[:, None]]          # [S,H]
-    lam_del = jnp.where(state.t - tf_steps >= state.admit_t[:, None],
-                        lam_del, 0.0)
-    arr, out, q_new = _queue_update(topo, dt, sim.backend, state.incidence,
-                                    path, state.q, lam_del, valid, bw,
-                                    keep=keep)
-    hist_q = state.hist_q.at[ptr].set(q_new)
-    hist_out = state.hist_out.at[ptr].set(out)
+    with jax.named_scope("queue"):
+        hop_delay_idx = jnp.mod(ptr - tf_steps, D)                # [S,H]
+        lam_del = hist_lam[hop_delay_idx, sidx[:, None]]          # [S,H]
+        lam_del = jnp.where(state.t - tf_steps >= state.admit_t[:, None],
+                            lam_del, 0.0)
+        arr, out, q_new = _queue_update(topo, dt, sim.backend, state.incidence,
+                                        path, state.q, lam_del, valid, bw,
+                                        keep=keep)
+        hist_q = state.hist_q.at[ptr].set(q_new)
+        hist_out = state.hist_out.at[ptr].set(out)
 
-    # -- feedback channels (mirror of step: gated at trace time) ----------
-    if sim.law.uses_pause:
-        pause_new = _pause_step(q_new, state.pause, cfg_slot)
-        hist_pause = state.hist_pause.at[ptr].set(pause_new)
-    else:
-        pause_new, hist_pause = None, None
-    if sim.law.uses_incast:
-        inc = _incast_count(state.q, path, valid, lam_del)
-        hist_inc = state.hist_inc.at[ptr].set(inc)
-    else:
-        hist_inc = None
+        # -- feedback channels (mirror of step: gated at trace time) ------
+        if sim.law.uses_pause:
+            pause_new = _pause_step(q_new, state.pause, cfg_slot)
+            hist_pause = state.hist_pause.at[ptr].set(pause_new)
+        else:
+            pause_new, hist_pause = None, None
+        if sim.law.uses_incast:
+            inc = _incast_count(state.q, path, valid, lam_del)
+            hist_inc = state.hist_inc.at[ptr].set(inc)
+        else:
+            hist_inc = None
 
     # -- delayed observation (see step; w_old before admission is the
     #    occupant's initial window, the padded engine's ring-init) --------
-    if sim.law.feedback == "hop":
-        tb_steps = jnp.clip(tf_steps, 1, D - 2)
-    else:
-        tb_steps = jnp.clip(state.rtt_steps[:, None] - tf_steps, 1, D - 2)
-    ohidx = jnp.mod(ptr - tb_steps, D)                        # [S,H]
-    ohprev = jnp.mod(ohidx - 1, D)
-    q_obs = hist_q[ohidx, path]
-    q_obs_prev = hist_q[ohprev, path]
-    qdot_obs = _nofma((q_obs - q_obs_prev) * (1.0 / dt))  # mirror of step
-    mu_obs = hist_out[ohidx, path]
-    qb_obs = q_obs / b_hop
-    if jit is not None:
-        qb_obs = qb_obs + jit[path]
-    theta_obs = tau + _hop_sum(
-        jnp.where(valid, qb_obs, 0.0))
-    wold_delay = jnp.clip(jnp.round(theta_obs / dt).astype(jnp.int32),
-                          1, D - 2)
-    w_old = hist_w[jnp.mod(ptr - wold_delay, D), sidx]
-    w_old = jnp.where(state.t - wold_delay >= state.admit_t, w_old,
-                      nic * tau)
-    buf_hop = jnp.concatenate(
-        [topo.buffer, jnp.asarray([1e30], jnp.float32)])[path]
-    ecn = jnp.max(jnp.where(valid, _marking(q_obs, buf_hop, cfg_slot), 0.0),
-                  axis=1)
+    with jax.named_scope("observe"):
+        if sim.law.feedback == "hop":
+            tb_steps = jnp.clip(tf_steps, 1, D - 2)
+        else:
+            tb_steps = jnp.clip(state.rtt_steps[:, None] - tf_steps, 1, D - 2)
+        ohidx = jnp.mod(ptr - tb_steps, D)                        # [S,H]
+        ohprev = jnp.mod(ohidx - 1, D)
+        q_obs = hist_q[ohidx, path]
+        q_obs_prev = hist_q[ohprev, path]
+        qdot_obs = _nofma((q_obs - q_obs_prev) * (1.0 / dt))  # mirror of step
+        mu_obs = hist_out[ohidx, path]
+        qb_obs = q_obs / b_hop
+        if jit is not None:
+            qb_obs = qb_obs + jit[path]
+        theta_obs = tau + _hop_sum(
+            jnp.where(valid, qb_obs, 0.0))
+        wold_delay = jnp.clip(jnp.round(theta_obs / dt).astype(jnp.int32),
+                              1, D - 2)
+        w_old = hist_w[jnp.mod(ptr - wold_delay, D), sidx]
+        w_old = jnp.where(state.t - wold_delay >= state.admit_t, w_old,
+                          nic * tau)
+        buf_hop = jnp.concatenate(
+            [topo.buffer, jnp.asarray([1e30], jnp.float32)])[path]
+        ecn = jnp.max(jnp.where(valid, _marking(q_obs, buf_hop, cfg_slot),
+                                0.0), axis=1)
 
-    upd = active & (t_sec >= state.next_update)
-    dt_obs = jnp.maximum(t_sec - state.last_update, dt)
-    obs = PathObs(q=q_obs, qdot=qdot_obs, mu=mu_obs, b=b_hop,
-                  valid=valid, theta=theta_obs, w_old=w_old, dt_obs=dt_obs,
-                  ecn_frac=ecn,
-                  pause=(hist_pause[ohidx, path]
-                         if sim.law.uses_pause else None),
-                  incast=(hist_inc[ohidx, path]
-                          if sim.law.uses_incast else None))
+        upd = active & (t_sec >= state.next_update)
+        dt_obs = jnp.maximum(t_sec - state.last_update, dt)
+        obs = PathObs(q=q_obs, qdot=qdot_obs, mu=mu_obs, b=b_hop,
+                      valid=valid, theta=theta_obs, w_old=w_old, dt_obs=dt_obs,
+                      ecn_frac=ecn,
+                      pause=(hist_pause[ohidx, path]
+                             if sim.law.uses_pause else None),
+                      incast=(hist_inc[ohidx, path]
+                              if sim.law.uses_incast else None))
 
     # -- control-law update (slot-gathered config) ------------------------
-    law_state, w, rate_cap = sim.law.update(
-        state.law, obs, state.w, state.rate_cap, upd, cfg_slot, t_sec)
-    w = jnp.clip(w, MTU, _nofma(_pin(8.0 * nic * tau)) +
-                 _nofma(_pin(8.0 * nic * theta_now)))
-    period = jnp.where(cfg.update_period > 0.0, cfg.update_period, theta_now)
-    next_update = jnp.where(upd, t_sec + period, state.next_update)
-    last_update = jnp.where(upd, t_sec, state.last_update)
+    with jax.named_scope("law"):
+        law_state, w, rate_cap = sim.law.update(
+            state.law, obs, state.w, state.rate_cap, upd, cfg_slot, t_sec)
+        w = jnp.clip(w, MTU, _nofma(_pin(8.0 * nic * tau)) +
+                     _nofma(_pin(8.0 * nic * theta_now)))
+        period = jnp.where(cfg.update_period > 0.0, cfg.update_period,
+                           theta_now)
+        next_update = jnp.where(upd, t_sec + period, state.next_update)
+        last_update = jnp.where(upd, t_sec, state.last_update)
 
     # -- flow progress; FCT scatters to the schedule-ordered [N] output ---
-    lam_good = lam if keep is None else lam * _hop_keep(keep, path, valid)
-    remaining = jnp.where(active,
-                          state.remaining - _nofma(_pin(lam_good * dt)),
-                          state.remaining)
-    done = active & (remaining <= 0.0)
-    fct = state.fct.at[jnp.where(done, state.slot_flow, N)].set(
-        jnp.where(done, t_sec + _nofma(tau / 2.0) - state.start, jnp.nan),
-        mode="drop")
-    # hold the slot until the flow's tail has drained into the queues
-    hold = jnp.max(jnp.where(valid, tf_steps, 0), axis=1)
-    expire = (occupied & (t_sec >= state.stop) &
-              (state.free_at == _INT32_MAX) & ~done)
-    free_at = jnp.where(done | expire, state.t + hold + 1, state.free_at)
+    with jax.named_scope("progress"):
+        lam_good = lam if keep is None else lam * _hop_keep(keep, path, valid)
+        remaining = jnp.where(active,
+                              state.remaining - _nofma(_pin(lam_good * dt)),
+                              state.remaining)
+        done = active & (remaining <= 0.0)
+        fct = state.fct.at[jnp.where(done, state.slot_flow, N)].set(
+            jnp.where(done, t_sec + _nofma(tau / 2.0) - state.start, jnp.nan),
+            mode="drop")
+        # hold the slot until the flow's tail has drained into the queues
+        hold = jnp.max(jnp.where(valid, tf_steps, 0), axis=1)
+        expire = (occupied & (t_sec >= state.stop) &
+                  (state.free_at == _INT32_MAX) & ~done)
+        free_at = jnp.where(done | expire, state.t + hold + 1, state.free_at)
 
-    new_state = state._replace(
-        t=state.t + 1, w=w, rate_cap=rate_cap, q=q_new, out_rate=out,
-        hist_lam=hist_lam, hist_q=hist_q, hist_out=hist_out, hist_w=hist_w,
-        remaining=remaining, fct=fct, free_at=free_at,
-        next_update=next_update, last_update=last_update, law=law_state,
-        pause=pause_new, hist_pause=hist_pause, hist_inc=hist_inc)
-    rec = Record(t=t_sec, q=q_new, w_sum=jnp.sum(jnp.where(active, w, 0.0)),
-                 thru=out, lam=jnp.sum(lam), lam_f=lam,
-                 n_active=jnp.sum(active.astype(jnp.int32)))
+        new_state = state._replace(
+            t=state.t + 1, w=w, rate_cap=rate_cap, q=q_new, out_rate=out,
+            hist_lam=hist_lam, hist_q=hist_q, hist_out=hist_out, hist_w=hist_w,
+            remaining=remaining, fct=fct, free_at=free_at,
+            next_update=next_update, last_update=last_update, law=law_state,
+            pause=pause_new, hist_pause=hist_pause, hist_inc=hist_inc)
+        rec = Record(t=t_sec, q=q_new,
+                     w_sum=jnp.sum(jnp.where(active, w, 0.0)),
+                     thru=out, lam=jnp.sum(lam), lam_f=lam,
+                     n_active=jnp.sum(active.astype(jnp.int32)))
     return new_state, rec
 
 
@@ -1099,22 +1109,23 @@ def _simulate_slots_chunked(sim: SlotSim, chunk: int, bw_fn, record: bool,
     if sim.backend == "fused":
         raise ValueError("chunk= is not supported on the fused backend")
     mega = sim.backend == "megakernel"
-    sched_np = jax.tree_util.tree_map(np.asarray, sim.sched)
-    N = int(sched_np.start.shape[0])
-    S = int(sim.slots)
-    Q = int(sim.topo.num_queues)
-    T = int(cfg.steps)
-    # C >= S makes the 1-tick fallback exact: one tick admits at most
-    # n_free <= S entries, which the C-clamped due count never truncates
-    C = min(max(int(chunk), S), max(N, 1))
-    start_np = np.asarray(sched_np.start, np.float32)
+    with obs.span("slots.prepare"):
+        sched_np = jax.tree_util.tree_map(np.asarray, sim.sched)
+        N = int(sched_np.start.shape[0])
+        S = int(sim.slots)
+        Q = int(sim.topo.num_queues)
+        T = int(cfg.steps)
+        # C >= S makes the 1-tick fallback exact: one tick admits at most
+        # n_free <= S entries, which the C-clamped due count never truncates
+        C = min(max(int(chunk), S), max(N, 1))
+        start_np = np.asarray(sched_np.start, np.float32)
+        first = _host_window(sched_np, 0, C, Q)
+        if mega:
+            from .megakernel import make_tick, _unpack_state
+            maxdeg = suggest_maxdeg(sched_np.path, Q, S)
 
     def make_simw(win, w0):
         return sim._replace(sched=win, n_flows=N, win_off=w0)
-
-    if mega:
-        from .megakernel import make_tick, _unpack_state
-        maxdeg = suggest_maxdeg(sched_np.path, Q, S)
 
     @jax.jit
     def init(win):
@@ -1160,7 +1171,8 @@ def _simulate_slots_chunked(sim: SlotSim, chunk: int, bw_fn, record: bool,
         seg_cache[L] = seg
         return seg
 
-    carry = init(_host_window(sched_np, 0, C, Q))
+    with obs.span("slots.call", program="init", ticks=0):
+        carry = init(first)
     recs = []
     t0 = 0
     seg_idx = 0
@@ -1208,20 +1220,25 @@ def _simulate_slots_chunked(sim: SlotSim, chunk: int, bw_fn, record: bool,
 
     while t0 < T:
         cursor = (carry.state.cursor if mega else carry.cursor)
-        w0 = int(jax.device_get(cursor))
-        safe = _safe_ticks(start_np, w0, C, t0, T, cfg.dt)
-        allowed = max(1, min(max(safe, 1), T - t0, _CHUNK_SEG_MAX))
-        # clamping the segment to the next cadence multiple / crash tick
-        # keeps boundaries landing EXACTLY on them: the pow2 floor below
-        # only shortens segments, and repeated shortening converges onto
-        # the clamp (e.g. 1000 = 512 + 256 + 128 + 64 + 32 + 8)
-        if every > 0:
-            allowed = min(allowed, ((t0 // every) + 1) * every - t0)
-        if crash_tick is not None and t0 < crash_tick:
-            allowed = min(allowed, crash_tick - t0)
-        L = 1 << (allowed.bit_length() - 1)       # pow2 floor, >= 1
-        win = _host_window(sched_np, w0, C, Q)
-        carry, rec = get_seg(L)(carry, win, jnp.asarray(w0, jnp.int32))
+        with obs.span("chunk.sync"):
+            w0 = int(jax.device_get(cursor))
+        with obs.span("chunk.window"):
+            safe = _safe_ticks(start_np, w0, C, t0, T, cfg.dt)
+            allowed = max(1, min(max(safe, 1), T - t0, _CHUNK_SEG_MAX))
+            # clamping the segment to the next cadence multiple / crash
+            # tick keeps boundaries landing EXACTLY on them: the pow2
+            # floor below only shortens segments, and repeated shortening
+            # converges onto the clamp (e.g. 1000 = 512+256+128+64+32+8)
+            if every > 0:
+                allowed = min(allowed, ((t0 // every) + 1) * every - t0)
+            if crash_tick is not None and t0 < crash_tick:
+                allowed = min(allowed, crash_tick - t0)
+            L = 1 << (allowed.bit_length() - 1)       # pow2 floor, >= 1
+            win = _host_window(sched_np, w0, C, Q)
+        with obs.span("slots.call", program="segment", ticks=L):
+            carry, rec = get_seg(L)(carry, win, jnp.asarray(w0, jnp.int32))
+        obs.count("chunk.segments")
+        obs.count("slots.ticks", L)
         if record:
             recs.append(rec)
         t0 += L
@@ -1239,15 +1256,16 @@ def _simulate_slots_chunked(sim: SlotSim, chunk: int, bw_fn, record: bool,
         if crash_seg is not None and seg_idx >= crash_seg:
             raise InjectedCrash(t0, seg_idx)
 
-    if record:
-        recs = jax.tree_util.tree_map(
-            lambda *xs: np.concatenate([np.asarray(x) for x in xs]),
-            *recs)
-    else:
-        recs = None
-    if mega:
-        return _unpack_state(carry, N, Q + 1), recs
-    return carry, recs
+    with obs.span("slots.finish"):
+        if record:
+            recs = jax.tree_util.tree_map(
+                lambda *xs: np.concatenate([np.asarray(x) for x in xs]),
+                *recs)
+        else:
+            recs = None
+        if mega:
+            return _unpack_state(carry, N, Q + 1), recs
+        return carry, recs
 
 
 def simulate_slots(topo: Topology, sched: FlowSchedule,
@@ -1305,12 +1323,14 @@ def simulate_slots(topo: Topology, sched: FlowSchedule,
     window (bit-identical to the single-shot run by the chunk
     contract); the fused backend rejects them.
     """
-    cfg = cfg or SimConfig()
-    _check_impair(impair, bw_fn, backend)
-    law = _resolve_law(law_name, backend)
-    law_cfg = law_cfg or default_law_config(sched)
-    sim = SlotSim(topo, sched, law, law_cfg, cfg, int(slots), backend,
-                  impair=impair)
+    obs.count("slots.calls")
+    with obs.span("slots.prepare"):
+        cfg = cfg or SimConfig()
+        _check_impair(impair, bw_fn, backend)
+        law = _resolve_law(law_name, backend)
+        law_cfg = law_cfg or default_law_config(sched)
+        sim = SlotSim(topo, sched, law, law_cfg, cfg, int(slots), backend,
+                      impair=impair)
     if checkpoint is not None or faults is not None or guard:
         if backend == "fused":
             raise UnsupportedFeature(
@@ -1324,9 +1344,12 @@ def simulate_slots(topo: Topology, sched: FlowSchedule,
                                        faults=faults, guard=guard)
     if chunk is not None:
         return _simulate_slots_chunked(sim, int(chunk), bw_fn, record)
+    obs.count("slots.ticks", int(cfg.steps))
     if backend == "megakernel":
         from .megakernel import simulate_slots_mega
-        return simulate_slots_mega(sim, bw_fn=bw_fn, record=record)
+        with obs.span("slots.call", program="megakernel",
+                      ticks=int(cfg.steps)):
+            return simulate_slots_mega(sim, bw_fn=bw_fn, record=record)
 
     @jax.jit
     def run():
@@ -1335,7 +1358,8 @@ def simulate_slots(topo: Topology, sched: FlowSchedule,
         return _scan_scenario(sim, state, bw_fn, None, record,
                               step_fn=slot_step)
 
-    return run()
+    with obs.span("slots.call", program="run", ticks=int(cfg.steps)):
+        return run()
 
 
 def resume_slots(topo: Topology, sched: FlowSchedule,

@@ -28,6 +28,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from . import obs
 from .fabric import (FabricRoutes, compile_routes, leaf_spine_fabric,
                      single_bottleneck_fabric)
 from .types import Flows, FlowSchedule, Topology, GBPS, US
@@ -42,15 +43,16 @@ def make_schedule(flows: Flows) -> FlowSchedule:
     bit-for-bit (slot i holds schedule entry i; see DESIGN.md section 12).
     ``order`` records the original index of each schedule entry.
     """
-    start = np.asarray(flows.start)
-    perm = np.argsort(start, kind="stable")
-    idx = jnp.asarray(perm.astype(np.int32))
-    return FlowSchedule(
-        path=flows.path[idx], tf_steps=flows.tf_steps[idx],
-        rtt_steps=flows.rtt_steps[idx], tau=flows.tau[idx],
-        nic_rate=flows.nic_rate[idx], size=flows.size[idx],
-        start=flows.start[idx], stop=flows.stop[idx],
-        weight=flows.weight[idx], order=idx)
+    with obs.span("schedule.build", flows=int(flows.start.shape[0])):
+        start = np.asarray(flows.start)
+        perm = np.argsort(start, kind="stable")
+        idx = jnp.asarray(perm.astype(np.int32))
+        return FlowSchedule(
+            path=flows.path[idx], tf_steps=flows.tf_steps[idx],
+            rtt_steps=flows.rtt_steps[idx], tau=flows.tau[idx],
+            nic_rate=flows.nic_rate[idx], size=flows.size[idx],
+            start=flows.start[idx], stop=flows.stop[idx],
+            weight=flows.weight[idx], order=idx)
 
 
 def schedule_as_flows(sched: FlowSchedule) -> Flows:

@@ -96,6 +96,7 @@ from ..kernels.queue_arrivals import (apply_loss, csr_gather_arrivals,
                                       stable_sort_ids, suggest_maxdeg)
 from ..launch.mesh import make_mesh
 from ..sharding.axes import axes_to_pspec
+from . import obs
 from .fluid import (_CHUNK_SEG_MAX, _INT32_MAX, _bandwidth, _buffer_caps,
                     _check_impair, _gather_law_cfg, _hop_keep, _hop_sum,
                     _host_window, _incast_count, _marking, _pause_step,
@@ -176,6 +177,7 @@ class ShardCarry(NamedTuple):
     ovf: Optional[jnp.ndarray]     # replicated structure-overflow flag
     sel: Optional[jnp.ndarray]     # [ndev, cap] send-side gather table
     rb_cur: Optional[jnp.ndarray]  # replicated cursor at last rebuild
+    fallback: Optional[jnp.ndarray] = None  # ticks that took ``_full``
 
 
 def _admit_global(simw: SlotSim, g: ShardGlob, t_sec):
@@ -363,8 +365,9 @@ def _shard_tick(simw: SlotSim, mi: ShardInfo, off, blk0,
     Sl = mi.Sl
     S = Sl * mi.ndev
     q1p = mi.Qb * mi.ndev if mi.use_csr else Q + 1
-    t_sec = _nofma(g.t.astype(jnp.float32) * dt)      # mirror of slot_step
-    ptr = jnp.mod(g.t, D)
+    with jax.named_scope("rates"):
+        t_sec = _nofma(g.t.astype(jnp.float32) * dt)  # mirror of slot_step
+        ptr = jnp.mod(g.t, D)
 
     # -- deferred ring-row writes: tick t-1's queue row lands here, at
     #    the start of tick t — its first possible read (every delayed
@@ -372,193 +375,204 @@ def _shard_tick(simw: SlotSim, mi: ShardInfo, off, blk0,
     #    keeps the big [D, q1p] rings update-in-place under XLA buffer
     #    assignment, while every row VALUE stays exactly the reference
     #    one (the driver applies the last pending row on exit).
-    ptr_prev = jnp.mod(g.t - 1, D)
-    hist_q = g.hist_q.at[ptr_prev].set(g.q)
-    hist_out = g.hist_out.at[ptr_prev].set(g.out_rate)
-    hist_pause = (g.hist_pause.at[ptr_prev].set(g.pause)
-                  if law.uses_pause else None)
-    hist_inc = (g.hist_inc.at[ptr_prev].set(g.inc_prev)
-                if law.uses_incast else None)
+    with jax.named_scope("queue"):
+        ptr_prev = jnp.mod(g.t - 1, D)
+        hist_q = g.hist_q.at[ptr_prev].set(g.q)
+        hist_out = g.hist_out.at[ptr_prev].set(g.out_rate)
+        hist_pause = (g.hist_pause.at[ptr_prev].set(g.pause)
+                      if law.uses_pause else None)
+        hist_inc = (g.hist_inc.at[ptr_prev].set(g.inc_prev)
+                    if law.uses_incast else None)
 
-    if simw.impair is not None and mi.use_csr and mi.ndev > 1:
-        # Impairment processes are stateless counter-based draws keyed
-        # on the GLOBAL link id, so each shard evaluates only its own
-        # queue-block slice of the regime (qid0 offset) and one small
-        # [3, Qb] all-gather assembles the full vectors — bitwise the
-        # replicated evaluation, at 1/ndev the per-device hash cost.
-        pz = jax.tree_util.tree_map(
-            lambda a: jax.lax.dynamic_slice_in_dim(
-                jnp.concatenate([a, jnp.zeros((q1p - Q,), a.dtype)]),
-                blk0, mi.Qb, 0),
-            simw.impair)
-        rows = jnp.stack([link_bw_at(t_sec, pz, qid0=blk0),
-                          1.0 - link_loss_at(t_sec, pz, qid0=blk0),
-                          link_jitter_at(t_sec, pz, qid0=blk0)])
-        gathered = jax.lax.all_gather(rows, _AX, axis=1, tiled=True)
-        bw = jnp.concatenate([gathered[0, :Q],
-                              jnp.asarray([1e15], jnp.float32)])
-        keep = _pin(jnp.concatenate([gathered[1, :Q],
-                                     jnp.asarray([1.0], jnp.float32)]))
-        jit_v = _pin(jnp.concatenate([gathered[2, :Q],
-                                      jnp.asarray([0.0], jnp.float32)]))
-    else:
-        bw = _bandwidth(topo, bw_fn, t_sec, simw.impair)  # [Q+1]
-        keep, jit_v = (impair_vectors(t_sec, simw.impair)
-                       if simw.impair is not None else (None, None))
+    with jax.named_scope("rates"):
+        if simw.impair is not None and mi.use_csr and mi.ndev > 1:
+            # Impairment processes are stateless counter-based draws keyed
+            # on the GLOBAL link id, so each shard evaluates only its own
+            # queue-block slice of the regime (qid0 offset) and one small
+            # [3, Qb] all-gather assembles the full vectors — bitwise the
+            # replicated evaluation, at 1/ndev the per-device hash cost.
+            pz = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_slice_in_dim(
+                    jnp.concatenate([a, jnp.zeros((q1p - Q,), a.dtype)]),
+                    blk0, mi.Qb, 0),
+                simw.impair)
+            rows = jnp.stack([link_bw_at(t_sec, pz, qid0=blk0),
+                              1.0 - link_loss_at(t_sec, pz, qid0=blk0),
+                              link_jitter_at(t_sec, pz, qid0=blk0)])
+            gathered = jax.lax.all_gather(rows, _AX, axis=1, tiled=True)
+            bw = jnp.concatenate([gathered[0, :Q],
+                                  jnp.asarray([1e15], jnp.float32)])
+            keep = _pin(jnp.concatenate([gathered[1, :Q],
+                                         jnp.asarray([1.0], jnp.float32)]))
+            jit_v = _pin(jnp.concatenate([gathered[2, :Q],
+                                          jnp.asarray([0.0], jnp.float32)]))
+        else:
+            bw = _bandwidth(topo, bw_fn, t_sec, simw.impair)  # [Q+1]
+            keep, jit_v = (impair_vectors(t_sec, simw.impair)
+                           if simw.impair is not None else (None, None))
 
     def sl(x):
         return jax.lax.dynamic_slice_in_dim(x, off, Sl, 0)
 
     # -- admit / retire: replicated int bookkeeping, local metadata -------
-    g2, occupied, admit, gw, gf = _admit_global(simw, g, t_sec)
+    with jax.named_scope("admit"):
+        g2, occupied, admit, gw, gf = _admit_global(simw, g, t_sec)
 
-    adm_l = sl(admit)
-    gw_l, gf_l = sl(gw), sl(gf)
-    free_at_l, occ_l = sl(g2.free_at), sl(occupied)
-    sched = simw.sched
-    cfg_slot = _gather_law_cfg(simw.law_cfg, gf_l, N)
+        adm_l = sl(admit)
+        gw_l, gf_l = sl(gw), sl(gf)
+        free_at_l, occ_l = sl(g2.free_at), sl(occupied)
+        sched = simw.sched
+        cfg_slot = _gather_law_cfg(simw.law_cfg, gf_l, N)
 
-    # schedule gathers at [Sl]: same elementwise selects as the reference
-    # [S] ones, restricted to this shard's slice
-    adm2 = adm_l[:, None]
-    path_l = jnp.where(adm2, sched.path[gw_l], loc.path)
-    tf_l = jnp.where(adm2, sched.tf_steps[gw_l], loc.tf_steps)
-    rtt_l = jnp.where(adm_l, sched.rtt_steps[gw_l], loc.rtt_steps)
-    tau_l = jnp.where(adm_l, sched.tau[gw_l], loc.tau)
-    nic_l = jnp.where(adm_l, sched.nic_rate[gw_l], loc.nic_rate)
-    start_l = jnp.where(adm_l, sched.start[gw_l], loc.start)
-    stop_l = jnp.where(adm_l, sched.stop[gw_l], loc.stop)
-    admit_t_l = jnp.where(adm_l, g.t, loc.admit_t)
+        # schedule gathers at [Sl]: same elementwise selects as the reference
+        # [S] ones, restricted to this shard's slice
+        adm2 = adm_l[:, None]
+        path_l = jnp.where(adm2, sched.path[gw_l], loc.path)
+        tf_l = jnp.where(adm2, sched.tf_steps[gw_l], loc.tf_steps)
+        rtt_l = jnp.where(adm_l, sched.rtt_steps[gw_l], loc.rtt_steps)
+        tau_l = jnp.where(adm_l, sched.tau[gw_l], loc.tau)
+        nic_l = jnp.where(adm_l, sched.nic_rate[gw_l], loc.nic_rate)
+        start_l = jnp.where(adm_l, sched.start[gw_l], loc.start)
+        stop_l = jnp.where(adm_l, sched.stop[gw_l], loc.stop)
+        admit_t_l = jnp.where(adm_l, g.t, loc.admit_t)
 
     # -- halo-table rebuild: batched to rb_every-tick windows (a freshly
     #    admitted slot contributes exactly +0.0 for its first min-tf
     #    ticks, so the stale tables stay bit-exact until then) ------------
-    if mi.use_csr:
-        def rebuild(_):
-            s_tab, qid, ovf_cap = _halo_send_tables(path_l, mi, Q)
-            rqid = jax.lax.all_to_all(qid, _AX, split_axis=0,
-                                      concat_axis=0)
-            inv2, ovf_deg = _halo_recv_csr(rqid, mi)
-            ovf2 = jax.lax.psum((ovf_cap | ovf_deg).astype(jnp.int32),
-                                _AX) > 0
-            return s_tab, inv2, ovf2, g2.cursor
+    with jax.named_scope("halo"):
+        if mi.use_csr:
+            def rebuild(_):
+                s_tab, qid, ovf_cap = _halo_send_tables(path_l, mi, Q)
+                rqid = jax.lax.all_to_all(qid, _AX, split_axis=0,
+                                          concat_axis=0)
+                inv2, ovf_deg = _halo_recv_csr(rqid, mi)
+                ovf2 = jax.lax.psum((ovf_cap | ovf_deg).astype(jnp.int32),
+                                    _AX) > 0
+                return s_tab, inv2, ovf2, g2.cursor
 
-        def keep_tabs(_):
-            return carry.sel, carry.inv, carry.ovf, carry.rb_cur
+            def keep_tabs(_):
+                return carry.sel, carry.inv, carry.ovf, carry.rb_cur
 
-        do_rb = ((g2.cursor > carry.rb_cur) &
-                 (jnp.mod(g.t, mi.rb_every) == 0))
-        sel_t, inv, ovf, rb_cur = jax.lax.cond(do_rb, rebuild, keep_tabs, 0)
-    else:
-        sel_t, inv, ovf, rb_cur = None, None, None, None
+            do_rb = ((g2.cursor > carry.rb_cur) &
+                     (jnp.mod(g.t, mi.rb_every) == 0))
+            sel_t, inv, ovf, rb_cur = jax.lax.cond(do_rb, rebuild,
+                                                   keep_tabs, 0)
+        else:
+            sel_t, inv, ovf, rb_cur = None, None, None, None
 
     def _sel(new, old):
         m = adm_l.reshape(adm_l.shape + (1,) * (old.ndim - 1))
         return jnp.where(m, new, old)
 
-    law_state = jax.tree_util.tree_map(
-        _sel, law.init(Sl, cfg_slot), loc.law)
-    w_cur = _sel(nic_l * tau_l, loc.w)
-    rate_cap = _sel(jnp.full((Sl,), jnp.inf, jnp.float32), loc.rate_cap)
-    remaining = _sel(sched.size[gw_l].astype(jnp.float32), loc.remaining)
-    next_update = _sel((start_l + tau_l).astype(jnp.float32),
-                       loc.next_update)
-    last_update = _sel(start_l.astype(jnp.float32), loc.last_update)
+    with jax.named_scope("admit"):
+        law_state = jax.tree_util.tree_map(
+            _sel, law.init(Sl, cfg_slot), loc.law)
+        w_cur = _sel(nic_l * tau_l, loc.w)
+        rate_cap = _sel(jnp.full((Sl,), jnp.inf, jnp.float32), loc.rate_cap)
+        remaining = _sel(sched.size[gw_l].astype(jnp.float32), loc.remaining)
+        next_update = _sel((start_l + tau_l).astype(jnp.float32),
+                           loc.next_update)
+        last_update = _sel(start_l.astype(jnp.float32), loc.last_update)
 
     # -- instantaneous RTT and send rates (this shard's slot block) -------
-    sidx_l = jnp.arange(Sl)
-    active = (occ_l & (t_sec >= start_l) & (remaining > 0.0) &
-              (t_sec < stop_l))
-    q_hop = g2.q[path_l]                              # [Sl, H]
-    b_hop = _pin(bw[path_l])
-    valid = path_l < Q
-    qb_now = q_hop / b_hop
-    if jit_v is not None:
-        qb_now = qb_now + jit_v[path_l]
-    theta_now = tau_l + _hop_sum(jnp.where(valid, qb_now, 0.0))
-    lam = jnp.where(active,
-                    jnp.minimum(jnp.minimum(_pin(w_cur / theta_now),
-                                            rate_cap),
-                                nic_l), 0.0)
-    hist_lam = loc.hist_lam.at[ptr].set(lam)
-    hist_w = loc.hist_w.at[ptr].set(w_cur)
+    with jax.named_scope("rates"):
+        sidx_l = jnp.arange(Sl)
+        active = (occ_l & (t_sec >= start_l) & (remaining > 0.0) &
+                  (t_sec < stop_l))
+        q_hop = g2.q[path_l]                              # [Sl, H]
+        b_hop = _pin(bw[path_l])
+        valid = path_l < Q
+        qb_now = q_hop / b_hop
+        if jit_v is not None:
+            qb_now = qb_now + jit_v[path_l]
+        theta_now = tau_l + _hop_sum(jnp.where(valid, qb_now, 0.0))
+        lam = jnp.where(active,
+                        jnp.minimum(jnp.minimum(_pin(w_cur / theta_now),
+                                                rate_cap),
+                                    nic_l), 0.0)
+        hist_lam = loc.hist_lam.at[ptr].set(lam)
+        hist_w = loc.hist_w.at[ptr].set(w_cur)
 
-    hop_delay_idx = jnp.mod(ptr - tf_l, D)            # [Sl, H]
-    lam_del = hist_lam[hop_delay_idx, sidx_l[:, None]]
-    lam_del = jnp.where(g.t - tf_l >= admit_t_l[:, None], lam_del, 0.0)
-    contrib_l = jnp.where(valid, lam_del, 0.0)
+    with jax.named_scope("queue"):
+        hop_delay_idx = jnp.mod(ptr - tf_l, D)            # [Sl, H]
+        lam_del = hist_lam[hop_delay_idx, sidx_l[:, None]]
+        lam_del = jnp.where(g.t - tf_l >= admit_t_l[:, None], lam_del, 0.0)
+        contrib_l = jnp.where(valid, lam_del, 0.0)
 
     # -- delayed observation (local reads of replicated rings) ------------
     # Every ring read is at least one tick in the past (tb, wold_delay
     # >= 1 and < D), so the observation/law half never touches this
     # tick's queue fold — which lets its gather rows ride the same
     # collective as the queue blocks below.
-    if law.feedback == "hop":
-        tb_steps = jnp.clip(tf_l, 1, D - 2)
-    else:
-        tb_steps = jnp.clip(rtt_l[:, None] - tf_l, 1, D - 2)
-    ohidx = jnp.mod(ptr - tb_steps, D)                # [Sl, H]
-    ohprev = jnp.mod(ohidx - 1, D)
-    q_obs = hist_q[ohidx, path_l]
-    q_obs_prev = hist_q[ohprev, path_l]
-    qdot_obs = _nofma((q_obs - q_obs_prev) * (1.0 / dt))
-    mu_obs = hist_out[ohidx, path_l]
-    qb_obs = q_obs / b_hop
-    if jit_v is not None:
-        qb_obs = qb_obs + jit_v[path_l]
-    theta_obs = tau_l + _hop_sum(jnp.where(valid, qb_obs, 0.0))
-    wold_delay = jnp.clip(jnp.round(theta_obs / dt).astype(jnp.int32),
-                          1, D - 2)
-    w_old = hist_w[jnp.mod(ptr - wold_delay, D), sidx_l]
-    w_old = jnp.where(g.t - wold_delay >= admit_t_l, w_old,
-                      nic_l * tau_l)
-    buf_hop = jnp.concatenate(
-        [topo.buffer, jnp.asarray([1e30], jnp.float32)])[path_l]
-    ecn = jnp.max(jnp.where(valid, _marking(q_obs, buf_hop, cfg_slot),
-                            0.0), axis=1)
+    with jax.named_scope("observe"):
+        if law.feedback == "hop":
+            tb_steps = jnp.clip(tf_l, 1, D - 2)
+        else:
+            tb_steps = jnp.clip(rtt_l[:, None] - tf_l, 1, D - 2)
+        ohidx = jnp.mod(ptr - tb_steps, D)                # [Sl, H]
+        ohprev = jnp.mod(ohidx - 1, D)
+        q_obs = hist_q[ohidx, path_l]
+        q_obs_prev = hist_q[ohprev, path_l]
+        qdot_obs = _nofma((q_obs - q_obs_prev) * (1.0 / dt))
+        mu_obs = hist_out[ohidx, path_l]
+        qb_obs = q_obs / b_hop
+        if jit_v is not None:
+            qb_obs = qb_obs + jit_v[path_l]
+        theta_obs = tau_l + _hop_sum(jnp.where(valid, qb_obs, 0.0))
+        wold_delay = jnp.clip(jnp.round(theta_obs / dt).astype(jnp.int32),
+                              1, D - 2)
+        w_old = hist_w[jnp.mod(ptr - wold_delay, D), sidx_l]
+        w_old = jnp.where(g.t - wold_delay >= admit_t_l, w_old,
+                          nic_l * tau_l)
+        buf_hop = jnp.concatenate(
+            [topo.buffer, jnp.asarray([1e30], jnp.float32)])[path_l]
+        ecn = jnp.max(jnp.where(valid, _marking(q_obs, buf_hop, cfg_slot),
+                                0.0), axis=1)
 
-    upd = active & (t_sec >= next_update)
-    dt_obs = jnp.maximum(t_sec - last_update, dt)
-    obs = PathObs(q=q_obs, qdot=qdot_obs, mu=mu_obs, b=b_hop,
-                  valid=valid, theta=theta_obs, w_old=w_old,
-                  dt_obs=dt_obs, ecn_frac=ecn,
-                  pause=(hist_pause[ohidx, path_l]
-                         if law.uses_pause else None),
-                  incast=(hist_inc[ohidx, path_l]
-                          if law.uses_incast else None))
+        upd = active & (t_sec >= next_update)
+        dt_obs = jnp.maximum(t_sec - last_update, dt)
+        obs = PathObs(q=q_obs, qdot=qdot_obs, mu=mu_obs, b=b_hop,
+                      valid=valid, theta=theta_obs, w_old=w_old,
+                      dt_obs=dt_obs, ecn_frac=ecn,
+                      pause=(hist_pause[ohidx, path_l]
+                             if law.uses_pause else None),
+                      incast=(hist_inc[ohidx, path_l]
+                              if law.uses_incast else None))
 
     # -- control-law update (shard-local) ---------------------------------
-    law_state, w_new, rate_cap = law.update(
-        law_state, obs, w_cur, rate_cap, upd, cfg_slot, t_sec)
-    w_new = jnp.clip(w_new, MTU, _nofma(_pin(8.0 * nic_l * tau_l)) +
-                     _nofma(_pin(8.0 * nic_l * theta_now)))
-    period = jnp.where(cfg.update_period > 0.0, cfg.update_period,
-                       theta_now)
-    next_update = jnp.where(upd, t_sec + period, next_update)
-    last_update = jnp.where(upd, t_sec, last_update)
+    with jax.named_scope("law"):
+        law_state, w_new, rate_cap = law.update(
+            law_state, obs, w_cur, rate_cap, upd, cfg_slot, t_sec)
+        w_new = jnp.clip(w_new, MTU, _nofma(_pin(8.0 * nic_l * tau_l)) +
+                         _nofma(_pin(8.0 * nic_l * theta_now)))
+        period = jnp.where(cfg.update_period > 0.0, cfg.update_period,
+                           theta_now)
+        next_update = jnp.where(upd, t_sec + period, next_update)
+        last_update = jnp.where(upd, t_sec, last_update)
 
     # -- flow progress; FCT scatters into this shard's [N] buffer ---------
-    lam_good = (lam if keep is None
-                else lam * _hop_keep(keep, path_l, valid))
-    remaining = jnp.where(active,
-                          remaining - _nofma(_pin(lam_good * dt)),
-                          remaining)
-    done = active & (remaining <= 0.0)
-    fct = loc.fct.at[0, jnp.where(done, sl(g2.slot_flow), N)].set(
-        jnp.where(done, t_sec + _nofma(tau_l / 2.0) - start_l, jnp.nan),
-        mode="drop")
-    hold = jnp.max(jnp.where(valid, tf_l, 0), axis=1)
-    expire = (occ_l & (t_sec >= stop_l) & (free_at_l == _INT32_MAX) &
-              ~done)
+    with jax.named_scope("progress"):
+        lam_good = (lam if keep is None
+                    else lam * _hop_keep(keep, path_l, valid))
+        remaining = jnp.where(active,
+                              remaining - _nofma(_pin(lam_good * dt)),
+                              remaining)
+        done = active & (remaining <= 0.0)
+        fct = loc.fct.at[0, jnp.where(done, sl(g2.slot_flow), N)].set(
+            jnp.where(done, t_sec + _nofma(tau_l / 2.0) - start_l, jnp.nan),
+            mode="drop")
+        hold = jnp.max(jnp.where(valid, tf_l, 0), axis=1)
+        expire = (occ_l & (t_sec >= stop_l) & (free_at_l == _INT32_MAX) &
+                  ~done)
 
-    # packed per-slot tail rows: retire/hold (+ the recorded rows);
-    # hold <= D-2 < 2^24 is exact in f32
-    trows = [(done | expire).astype(jnp.float32),
-             hold.astype(jnp.float32)]
-    if record:
-        trows += [lam, active.astype(jnp.float32),
-                  jnp.where(active, w_new, 0.0)]
-    k = len(trows)
+        # packed per-slot tail rows: retire/hold (+ the recorded rows);
+        # hold <= D-2 < 2^24 is exact in f32
+        trows = [(done | expire).astype(jnp.float32),
+                 hold.astype(jnp.float32)]
+        if record:
+            trows += [lam, active.astype(jnp.float32),
+                      jnp.where(active, w_new, 0.0)]
+        k = len(trows)
 
     # -- queue update (mirror of fluid._queue_update, reference path) -----
     # Each queue's in-order add chain is replayed wholly on the shard
@@ -598,93 +612,106 @@ def _shard_tick(simw: SlotSim, mi: ShardInfo, off, blk0,
             return jax.lax.dynamic_slice_in_dim(jnp.stack(rows), blk0,
                                                 mi.Qb, 1)
 
-        ab = jax.lax.cond(ovf, _full, _halo, contrib_l)   # [nb, Qb]
+        with jax.named_scope("halo"):
+            ab = jax.lax.cond(ovf, _full, _halo, contrib_l)   # [nb, Qb]
+            fallback = carry.fallback + ovf.astype(jnp.int32)
         # block-local integration: elementwise slices of the reference
         # [Q+1] chain (identical bits), pad rows pinned at exactly 0.0
-        gidx = blk0 + jnp.arange(mi.Qb, dtype=jnp.int32)
-        zpad = jnp.zeros((q1p - (Q + 1),), jnp.float32)
-        bw_b = jax.lax.dynamic_slice_in_dim(
-            jnp.concatenate([bw, zpad]), blk0, mi.Qb, 0)
-        cap_tabs = _block_caps_tables(topo, mi, q1p)
-        if cap_tabs[0].shape[2] <= 64:
-            caps_b = _block_caps(topo, cap_tabs, g2.q, blk0 // mi.Qb, gidx)
-        else:   # pathological switch degree: replicated reference caps
-            caps = _buffer_caps(topo, jax.lax.slice_in_dim(g2.q, 0, Q + 1))
-            caps_b = jax.lax.dynamic_slice_in_dim(
-                jnp.concatenate([caps, jnp.full_like(zpad, 1e30)]),
-                blk0, mi.Qb, 0)
-        q_b = jax.lax.dynamic_slice_in_dim(g2.q, blk0, mi.Qb, 0)
-        arr_b = ab[0]
-        if keep is not None:
-            # loss folds into the ACCUMULATED arrivals — elementwise on
-            # the block, exactly as the reference full-vector fold
-            keep_b = jax.lax.dynamic_slice_in_dim(
-                jnp.concatenate([keep, jnp.ones_like(zpad)]),
-                blk0, mi.Qb, 0)
-            arr_b = apply_loss(arr_b, keep_b)
-        qn_b = jnp.clip(q_b + _nofma(_pin((arr_b - bw_b) * dt)),
-                        0.0, caps_b)
-        out_b = jnp.where(q_b > 0.0, bw_b, jnp.minimum(arr_b, bw_b))
-        qn_b = jnp.where(gidx >= Q, 0.0, qn_b)   # sentinel + pad rows
-        brows = [qn_b, out_b] + ([ab[1]] if law.uses_incast else [])
-        nb2 = len(brows)
+        with jax.named_scope("queue"):
+            gidx = blk0 + jnp.arange(mi.Qb, dtype=jnp.int32)
+            zpad = jnp.zeros((q1p - (Q + 1),), jnp.float32)
+            bw_b = jax.lax.dynamic_slice_in_dim(
+                jnp.concatenate([bw, zpad]), blk0, mi.Qb, 0)
+            cap_tabs = _block_caps_tables(topo, mi, q1p)
+            if cap_tabs[0].shape[2] <= 64:
+                caps_b = _block_caps(topo, cap_tabs, g2.q, blk0 // mi.Qb,
+                                     gidx)
+            else:   # pathological switch degree: replicated reference caps
+                caps = _buffer_caps(topo,
+                                    jax.lax.slice_in_dim(g2.q, 0, Q + 1))
+                caps_b = jax.lax.dynamic_slice_in_dim(
+                    jnp.concatenate([caps, jnp.full_like(zpad, 1e30)]),
+                    blk0, mi.Qb, 0)
+            q_b = jax.lax.dynamic_slice_in_dim(g2.q, blk0, mi.Qb, 0)
+            arr_b = ab[0]
+            if keep is not None:
+                # loss folds into the ACCUMULATED arrivals — elementwise
+                # on the block, exactly as the reference full-vector fold
+                keep_b = jax.lax.dynamic_slice_in_dim(
+                    jnp.concatenate([keep, jnp.ones_like(zpad)]),
+                    blk0, mi.Qb, 0)
+                arr_b = apply_loss(arr_b, keep_b)
+            qn_b = jnp.clip(q_b + _nofma(_pin((arr_b - bw_b) * dt)),
+                            0.0, caps_b)
+            out_b = jnp.where(q_b > 0.0, bw_b, jnp.minimum(arr_b, bw_b))
+            qn_b = jnp.where(gidx >= Q, 0.0, qn_b)   # sentinel + pad rows
+            brows = [qn_b, out_b] + ([ab[1]] if law.uses_incast else [])
+            nb2 = len(brows)
 
         # ONE packed all-gather moves the queue blocks and the slot tail
-        flat = jnp.concatenate([jnp.stack(brows).reshape(-1),
-                                jnp.stack(trows).reshape(-1)])
-        gg = jax.lax.all_gather(flat, _AX, axis=0, tiled=False)
-        blk = (gg[:, :nb2 * mi.Qb].reshape(mi.ndev, nb2, mi.Qb)
-               .transpose(1, 0, 2).reshape(nb2, q1p))
-        tail = (gg[:, nb2 * mi.Qb:].reshape(mi.ndev, k, Sl)
-                .transpose(1, 0, 2).reshape(k, S))
+        with jax.named_scope("halo"):
+            flat = jnp.concatenate([jnp.stack(brows).reshape(-1),
+                                    jnp.stack(trows).reshape(-1)])
+            gg = jax.lax.all_gather(flat, _AX, axis=0, tiled=False)
+            blk = (gg[:, :nb2 * mi.Qb].reshape(mi.ndev, nb2, mi.Qb)
+                   .transpose(1, 0, 2).reshape(nb2, q1p))
+            tail = (gg[:, nb2 * mi.Qb:].reshape(mi.ndev, k, Sl)
+                    .transpose(1, 0, 2).reshape(k, S))
         q_new, out = blk[0], blk[1]
         inc_now = blk[2] if law.uses_incast else None
     else:
-        caps = _buffer_caps(topo, g2.q)
-        contrib = jax.lax.all_gather(contrib_l, _AX, axis=0, tiled=True)
-        path_f = jax.lax.all_gather(path_l, _AX, axis=0, tiled=True)
-        arr = ordered_scatter_add(jnp.zeros_like(g2.q), path_f, contrib)
-        inc_now = (_incast_count(g2.q, path_f, path_f < Q, contrib)
-                   if law.uses_incast else None)
-        if keep is not None:
-            arr = apply_loss(arr, keep)
-        q_new = jnp.clip(g2.q + _nofma(_pin((arr - bw) * dt)), 0.0, caps)
-        out = jnp.where(g2.q > 0.0, bw, jnp.minimum(arr, bw))
-        q_new = q_new.at[-1].set(0.0)
-        tail = jax.lax.all_gather(jnp.stack(trows), _AX, axis=1,
-                                  tiled=True)
+        fallback = None
+        with jax.named_scope("halo"):
+            contrib = jax.lax.all_gather(contrib_l, _AX, axis=0, tiled=True)
+            path_f = jax.lax.all_gather(path_l, _AX, axis=0, tiled=True)
+        with jax.named_scope("queue"):
+            caps = _buffer_caps(topo, g2.q)
+            arr = ordered_scatter_add(jnp.zeros_like(g2.q), path_f, contrib)
+            inc_now = (_incast_count(g2.q, path_f, path_f < Q, contrib)
+                       if law.uses_incast else None)
+            if keep is not None:
+                arr = apply_loss(arr, keep)
+            q_new = jnp.clip(g2.q + _nofma(_pin((arr - bw) * dt)), 0.0,
+                             caps)
+            out = jnp.where(g2.q > 0.0, bw, jnp.minimum(arr, bw))
+            q_new = q_new.at[-1].set(0.0)
+        with jax.named_scope("halo"):
+            tail = jax.lax.all_gather(jnp.stack(trows), _AX, axis=1,
+                                      tiled=True)
 
     # -- feedback channels (replicated; mirror of slot_step). The fresh
     #    rows (q_new/out/pause_new/inc_now) stay in the flat carry
     #    leaves; next tick's deferred write rings them. -------------------
-    pause_new = (_pause_step(q_new, g2.pause, cfg_slot)
-                 if law.uses_pause else None)
+    with jax.named_scope("queue"):
+        pause_new = (_pause_step(q_new, g2.pause, cfg_slot)
+                     if law.uses_pause else None)
 
-    free_at = jnp.where(tail[0] > 0.0,
-                        g.t + tail[1].astype(jnp.int32) + 1, g2.free_at)
+    with jax.named_scope("progress"):
+        free_at = jnp.where(tail[0] > 0.0,
+                            g.t + tail[1].astype(jnp.int32) + 1, g2.free_at)
 
-    new_carry = ShardCarry(
-        g=g2._replace(t=g.t + 1, q=q_new, out_rate=out, hist_q=hist_q,
-                      hist_out=hist_out, free_at=free_at,
-                      pause=pause_new, hist_pause=hist_pause,
-                      hist_inc=hist_inc,
-                      inc_prev=inc_now if law.uses_incast else None),
-        l=ShardLoc(w=w_new, rate_cap=rate_cap, remaining=remaining,
-                   next_update=next_update, last_update=last_update,
-                   admit_t=admit_t_l, path=path_l, tf_steps=tf_l,
-                   rtt_steps=rtt_l, tau=tau_l, nic_rate=nic_l,
-                   start=start_l, stop=stop_l,
-                   hist_lam=hist_lam, hist_w=hist_w, law=law_state,
-                   fct=fct),
-        inv=inv, ovf=ovf, sel=sel_t, rb_cur=rb_cur)
-    if record:
-        lam_full, act_f, w_act = tail[2], tail[3], tail[4]
-        rec = Record(t=t_sec, q=q_new[:Q + 1], w_sum=jnp.sum(w_act),
-                     thru=out[:Q + 1], lam=jnp.sum(lam_full),
-                     lam_f=lam_full,
-                     n_active=jnp.sum(act_f.astype(jnp.int32)))
-    else:
-        rec = None
+        new_carry = ShardCarry(
+            g=g2._replace(t=g.t + 1, q=q_new, out_rate=out, hist_q=hist_q,
+                          hist_out=hist_out, free_at=free_at,
+                          pause=pause_new, hist_pause=hist_pause,
+                          hist_inc=hist_inc,
+                          inc_prev=inc_now if law.uses_incast else None),
+            l=ShardLoc(w=w_new, rate_cap=rate_cap, remaining=remaining,
+                       next_update=next_update, last_update=last_update,
+                       admit_t=admit_t_l, path=path_l, tf_steps=tf_l,
+                       rtt_steps=rtt_l, tau=tau_l, nic_rate=nic_l,
+                       start=start_l, stop=stop_l,
+                       hist_lam=hist_lam, hist_w=hist_w, law=law_state,
+                       fct=fct),
+            inv=inv, ovf=ovf, sel=sel_t, rb_cur=rb_cur, fallback=fallback)
+        if record:
+            lam_full, act_f, w_act = tail[2], tail[3], tail[4]
+            rec = Record(t=t_sec, q=q_new[:Q + 1], w_sum=jnp.sum(w_act),
+                         thru=out[:Q + 1], lam=jnp.sum(lam_full),
+                         lam_f=lam_full,
+                         n_active=jnp.sum(act_f.astype(jnp.int32)))
+        else:
+            rec = None
     return new_carry, rec
 
 
@@ -747,10 +774,11 @@ def _init_carry(simw: SlotSim, mi: ShardInfo) -> ShardCarry:
         ovf = jnp.asarray(False)
         sel = jnp.full((mi.ndev, mi.cap), Sl * H, jnp.int32)
         rb_cur = jnp.asarray(0, jnp.int32)
+        fallback = jnp.asarray(0, jnp.int32)
     else:
-        inv, ovf, sel, rb_cur = None, None, None, None
+        inv, ovf, sel, rb_cur, fallback = None, None, None, None, None
     return ShardCarry(g=g, l=loc, inv=inv, ovf=ovf, sel=sel,
-                      rb_cur=rb_cur)
+                      rb_cur=rb_cur, fallback=fallback)
 
 
 def _carry_specs(mesh, law_template, law: Law,
@@ -780,7 +808,8 @@ def _carry_specs(mesh, law_template, law: Law,
                       ovf=rep if use_csr else None,
                       sel=axes_to_pspec(("halo", None), mesh) if use_csr
                       else None,
-                      rb_cur=rep if use_csr else None)
+                      rb_cur=rep if use_csr else None,
+                      fallback=rep if use_csr else None)
 
 
 def _merge_fct(fct_parts: jnp.ndarray) -> jnp.ndarray:
@@ -947,75 +976,86 @@ def simulate_slots_sharded(topo: Topology, sched: FlowSchedule,
     mesh (the collectives no-op; this is the honest single-device
     baseline for scaling numbers), ``"auto"`` uses every local device.
     """
-    cfg = cfg or SimConfig()
-    _check_impair(impair, bw_fn, "reference")
-    law = _resolve_law(law_name, "reference")
-    law_cfg = law_cfg or default_law_config(sched)
-    ndev = resolve_devices(devices)
-    S = int(slots)
-    if S % ndev:
-        raise ValueError(f"slots={S} must divide over {ndev} devices")
-    if record and int(cfg.record_every) > 1:
-        raise ValueError("sharded runs record every tick; record_every "
-                         "> 1 is not supported")
-    sim = SlotSim(topo, sched, law, law_cfg, cfg, S, "reference",
-                  impair=impair)
-    sched_np = jax.tree_util.tree_map(np.asarray, sched)
-    N = int(sched_np.start.shape[0])
-    Q = int(topo.num_queues)
-    T = int(cfg.steps)
-    mi = _shard_geometry(sched_np, S, Q, ndev)
-    # C >= S keeps the 1-tick fallback exact (see _safe_ticks)
-    C = N if chunk is None else min(max(int(chunk), S), max(N, 1))
-    start_np = np.asarray(sched_np.start, np.float32)
+    obs.count("slots.calls")
+    with obs.span("slots.prepare"):
+        cfg = cfg or SimConfig()
+        _check_impair(impair, bw_fn, "reference")
+        law = _resolve_law(law_name, "reference")
+        law_cfg = law_cfg or default_law_config(sched)
+        ndev = resolve_devices(devices)
+        S = int(slots)
+        if S % ndev:
+            raise ValueError(f"slots={S} must divide over {ndev} devices")
+        if record and int(cfg.record_every) > 1:
+            raise ValueError("sharded runs record every tick; "
+                             "record_every > 1 is not supported")
+        sim = SlotSim(topo, sched, law, law_cfg, cfg, S, "reference",
+                      impair=impair)
+        sched_np = jax.tree_util.tree_map(np.asarray, sched)
+        N = int(sched_np.start.shape[0])
+        Q = int(topo.num_queues)
+        T = int(cfg.steps)
+        mi = _shard_geometry(sched_np, S, Q, ndev)
+        # C >= S keeps the 1-tick fallback exact (see _safe_ticks)
+        C = N if chunk is None else min(max(int(chunk), S), max(N, 1))
+        start_np = np.asarray(sched_np.start, np.float32)
 
-    mesh = make_mesh((ndev,), (_AX,))
-    init_j, get_seg = _sharded_programs(sim, mi, mesh, bw_fn, record)
-    carry = init_j(_host_window(sched_np, 0, C, Q),
-                   jnp.asarray(0, jnp.int32))
+        mesh = make_mesh((ndev,), (_AX,))
+        init_j, get_seg = _sharded_programs(sim, mi, mesh, bw_fn, record)
+        win = _host_window(sched_np, 0, C, Q)
+    with obs.span("slots.call", program="init", ticks=0):
+        carry = init_j(win, jnp.asarray(0, jnp.int32))
     recs = []
     t0 = 0
     while t0 < T:
-        w0 = int(jax.device_get(carry.g.cursor))
-        safe = _safe_ticks(start_np, w0, C, t0, T, cfg.dt)
-        if w0 + C >= N:
-            L = T - t0        # window covers the tail: one segment
-        else:
-            allowed = max(1, min(max(safe, 1), T - t0, _CHUNK_SEG_MAX))
-            L = 1 << (allowed.bit_length() - 1)
-        win = _host_window(sched_np, w0, C, Q)
-        carry, rec = get_seg(L)(carry, win, jnp.asarray(w0, jnp.int32))
+        with obs.span("chunk.sync"):
+            w0 = int(jax.device_get(carry.g.cursor))
+        with obs.span("chunk.window"):
+            safe = _safe_ticks(start_np, w0, C, t0, T, cfg.dt)
+            if w0 + C >= N:
+                L = T - t0        # window covers the tail: one segment
+            else:
+                allowed = max(1, min(max(safe, 1), T - t0, _CHUNK_SEG_MAX))
+                L = 1 << (allowed.bit_length() - 1)
+            win = _host_window(sched_np, w0, C, Q)
+        with obs.span("slots.call", program="segment", ticks=L):
+            carry, rec = get_seg(L)(carry, win, jnp.asarray(w0, jnp.int32))
+        obs.count("chunk.segments")
+        obs.count("slots.ticks", L)
         if record:
             recs.append(rec)
         t0 += L
+    if carry.fallback is not None:
+        obs.count("halo.fallback_ticks", carry.fallback)
 
-    if record:
-        recs = jax.tree_util.tree_map(
-            lambda *xs: np.concatenate([np.asarray(x) for x in xs]),
-            *recs)
-    else:
-        recs = None
-    g, loc = carry.g, carry.l
-    # ring the pending last row (the tick loop defers each row write to
-    # the next tick's start; see _shard_tick) so the returned histories
-    # match the reference state exactly
-    last = jnp.mod(g.t - 1, int(cfg.hist))
+    with obs.span("slots.finish"):
+        if record:
+            recs = jax.tree_util.tree_map(
+                lambda *xs: np.concatenate([np.asarray(x) for x in xs]),
+                *recs)
+        else:
+            recs = None
+        g, loc = carry.g, carry.l
+        # ring the pending last row (the tick loop defers each row write to
+        # the next tick's start; see _shard_tick) so the returned histories
+        # match the reference state exactly
+        last = jnp.mod(g.t - 1, int(cfg.hist))
 
-    def _ring(h, row):
-        return None if h is None else h.at[last].set(row)[:, :Q + 1]
+        def _ring(h, row):
+            return None if h is None else h.at[last].set(row)[:, :Q + 1]
 
-    state = SlotState(
-        t=g.t, cursor=g.cursor, hw=g.hw, slot_flow=g.slot_flow,
-        admit_t=loc.admit_t, free_at=g.free_at, path=loc.path,
-        tf_steps=loc.tf_steps, rtt_steps=loc.rtt_steps, tau=loc.tau,
-        nic_rate=loc.nic_rate, start=loc.start, stop=loc.stop, w=loc.w,
-        rate_cap=loc.rate_cap, q=g.q[:Q + 1], out_rate=g.out_rate[:Q + 1],
-        hist_lam=loc.hist_lam, hist_q=_ring(g.hist_q, g.q),
-        hist_out=_ring(g.hist_out, g.out_rate),
-        hist_w=loc.hist_w, remaining=loc.remaining,
-        next_update=loc.next_update, last_update=loc.last_update,
-        law=loc.law, fct=_merge_fct(loc.fct), incidence=None,
-        pause=None if g.pause is None else g.pause[:Q + 1],
-        hist_pause=_ring(g.hist_pause, g.pause),
-        hist_inc=_ring(g.hist_inc, g.inc_prev))
-    return state, recs
+        state = SlotState(
+            t=g.t, cursor=g.cursor, hw=g.hw, slot_flow=g.slot_flow,
+            admit_t=loc.admit_t, free_at=g.free_at, path=loc.path,
+            tf_steps=loc.tf_steps, rtt_steps=loc.rtt_steps, tau=loc.tau,
+            nic_rate=loc.nic_rate, start=loc.start, stop=loc.stop, w=loc.w,
+            rate_cap=loc.rate_cap, q=g.q[:Q + 1], out_rate=g.out_rate[:Q + 1],
+            hist_lam=loc.hist_lam, hist_q=_ring(g.hist_q, g.q),
+            hist_out=_ring(g.hist_out, g.out_rate),
+            hist_w=loc.hist_w, remaining=loc.remaining,
+            next_update=loc.next_update, last_update=loc.last_update,
+            law=loc.law, fct=_merge_fct(loc.fct), incidence=None,
+            pause=None if g.pause is None else g.pause[:Q + 1],
+            hist_pause=_ring(g.hist_pause, g.pause),
+            hist_inc=_ring(g.hist_inc, g.inc_prev))
+        return state, recs

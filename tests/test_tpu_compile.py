@@ -133,6 +133,13 @@ def test_sharded_tick_compiles_on_v5e_2x2_mesh(topo):
     carry = jax.eval_shape(init, win, w0)
     text = get_seg(cfg.steps).lower(carry, win, w0).compile().as_text()
     assert "all-to-all" in text
+    # the tick's phase scopes survive the TPU compiler as op metadata;
+    # every halo all-to-all sits in the ``halo`` scope
+    for phase in ("admit", "rates", "queue", "observe", "law", "progress",
+                  "halo"):
+        assert f"/{phase}/" in text, phase
+    a2a = [ln for ln in text.splitlines() if " all-to-all(" in ln]
+    assert a2a and all("/halo/" in ln for ln in a2a)
 
 
 def test_resolve_devices_never_clamps():

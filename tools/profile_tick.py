@@ -11,13 +11,17 @@ backend:
   * XLA cost analysis of the compiled program (flops / bytes accessed);
   * an HLO histogram of the scan body: op counts by kind, with the
     non-fusible kinds (scatter/gather/while/sort/reduce-window) called
-    out — these are the per-tick cost centers;
-  * optionally (--trace) a profiler-trace aggregation of per-thunk time.
+    out — these are the per-tick cost centers.
+
+Its wall clocks are those of the backend it runs on, and on the CPU no
+device speed; device time per tick and per tick phase comes from the
+benchmark's traced runs on the chip (``bench/run.py --trace 1``,
+``bench/lib/progtrace.py``; PERF.md).
 
 Usage:
     PYTHONPATH=src python tools/profile_tick.py [--hosts 256]
         [--load 0.6] [--steps 4096] [--slots 128] [--law powertcp]
-        [--backends reference,megakernel] [--repeats 3] [--trace]
+        [--backends reference,megakernel] [--repeats 3]
 
 Also wired as ``python -m benchmarks.run --profile`` (a reduced preset).
 """
@@ -25,9 +29,6 @@ from __future__ import annotations
 
 import argparse
 import collections
-import glob
-import gzip
-import json
 import os
 import re
 import sys
@@ -88,7 +89,7 @@ def body_histogram(hlo_text: str):
 
 
 def profile_backend(topo, sched, law: str, slots: int, steps: int,
-                    backend: str, repeats: int = 3, trace_dir=None):
+                    backend: str, repeats: int = 3):
     import numpy as np
     import jax
     from repro.core import SimConfig, simulate_slots
@@ -165,26 +166,7 @@ def profile_backend(topo, sched, law: str, slots: int, steps: int,
         "measured_over_roofline": round(
             out["us_per_tick"] / max(rf["roofline_us"], 1e-9), 1),
     }
-    if trace_dir:
-        with jax.profiler.trace(trace_dir):
-            jax.block_until_ready(compiled(arg0))
-        out["thunks_us_per_tick"] = aggregate_trace(trace_dir, steps)
     return out
-
-
-def aggregate_trace(trace_dir: str, steps: int, top: int = 12):
-    ev = collections.Counter()
-    for fn in glob.glob(os.path.join(trace_dir, "**", "*.trace.json.gz"),
-                        recursive=True):
-        with gzip.open(fn, "rt") as f:
-            data = json.load(f)
-        for e in data.get("traceEvents", []):
-            name = e.get("name", "")
-            if (e.get("ph") == "X" and "dur" in e and
-                    not name.startswith("$") and "Thunk" not in name and
-                    "Pjit" not in name):
-                ev[name] += e["dur"]
-    return {k: round(v / steps, 2) for k, v in ev.most_common(top)}
 
 
 def comm_report(topo, sched, slots: int, devices: int):
@@ -233,8 +215,6 @@ def main(argv=None):
     ap.add_argument("--law", default="powertcp")
     ap.add_argument("--backends", default="reference,megakernel")
     ap.add_argument("--repeats", type=int, default=3)
-    ap.add_argument("--trace", action="store_true",
-                    help="also aggregate a profiler trace per backend")
     ap.add_argument("--shard-devices", type=int, default=0,
                     help="also print the sharded engine's per-tick "
                          "communication census for this mesh width "
@@ -253,14 +233,12 @@ def main(argv=None):
     for be in a.backends.split(","):
         if not be.strip():
             continue
-        trace_dir = f"/tmp/profile_tick_{be}" if a.trace else None
         r = profile_backend(topo, sched, a.law, a.slots, a.steps,
-                            be.strip(), a.repeats, trace_dir)
+                            be.strip(), a.repeats)
         results.append(r)
         print(f"\n== {be} ==")
         for k, v in r.items():
-            if k in ("body_non_fusible", "thunks_us_per_tick",
-                     "roofline"):
+            if k in ("body_non_fusible", "roofline"):
                 print(f"  {k}:")
                 for kk, vv in v.items():
                     print(f"    {kk:42s} {vv}")
