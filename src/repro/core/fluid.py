@@ -58,6 +58,9 @@ expected marking fraction.
 """
 from __future__ import annotations
 
+import hashlib
+import threading
+from collections import OrderedDict
 from typing import Callable, List, NamedTuple, Optional, Union
 
 import numpy as np
@@ -1302,6 +1305,12 @@ def simulate_slots(topo: Topology, sched: FlowSchedule,
     dtypes are audited (``audit_carry_dtypes``) so a stray wide leaf
     cannot silently double the carried footprint.
 
+    The whole-trace run (reference and fused backends, no ``chunk``)
+    compiles once per static signature: the schedule and the [N]-axis
+    ``LawConfig`` leaves are arguments of a cached program, padded to
+    one count per bit length of max(N, S) (``_slot_program``; DESIGN.md
+    section 12), with bit-identical results.
+
     ``chunk=C`` streams the schedule through the scan in C-entry windows
     (reference and megakernel backends; DESIGN.md section 15): trace
     length then no longer bounds device memory — only O(C * H) schedule
@@ -1351,15 +1360,128 @@ def simulate_slots(topo: Topology, sched: FlowSchedule,
                       ticks=int(cfg.steps)):
             return simulate_slots_mega(sim, bw_fn=bw_fn, record=record)
 
-    @jax.jit
-    def run():
-        state = init_slot_state(sim)
-        audit_carry_dtypes(state)
-        return _scan_scenario(sim, state, bw_fn, None, record,
-                              step_fn=slot_step)
-
+    with obs.span("slots.prepare"):
+        run, args = _slot_program(sim, bw_fn, record)
+    N = int(sched.start.shape[0])
     with obs.span("slots.call", program="run", ticks=int(cfg.steps)):
-        return run()
+        final, recs = run(*args)
+    if int(final.fct.shape[0]) != N:
+        with obs.span("slots.finish"):
+            # on the host: an eager device slice compiles per flow count
+            final = final._replace(
+                fct=jnp.asarray(np.asarray(final.fct)[:N]))
+    return final, recs
+
+
+class _Ident:
+    """A cache-key part compared by identity. It holds the object, so
+    the object's ``id`` is never reused while the key lives."""
+    __slots__ = ("obj",)
+
+    def __init__(self, obj):
+        self.obj = obj
+
+    def __hash__(self):
+        return id(self.obj)
+
+    def __eq__(self, other):
+        return isinstance(other, _Ident) and other.obj is self.obj
+
+
+def _digest(tree) -> bytes:
+    """Content digest of a pytree of concrete arrays and scalars: its
+    structure and each leaf's type, weak type, dtype, shape and bytes."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    h = hashlib.blake2b(str(treedef).encode(), digest_size=16)
+    for x in leaves:
+        a = np.asarray(x)
+        h.update(f"{type(x).__name__}|{getattr(x, 'weak_type', '')}|"
+                 f"{a.dtype.str}|{a.shape}".encode())
+        h.update(a.tobytes())
+    return h.digest()
+
+
+_SLOT_PROGRAMS_MAX = 8
+_slot_programs: OrderedDict = OrderedDict()     # key -> jitted run, LRU
+_slot_programs_lock = threading.Lock()
+
+
+def _slot_program(sim: SlotSim, bw_fn, record: bool):
+    """The whole-trace slot program for ``sim`` and its arguments
+    (DESIGN.md section 12, program cache).
+
+    The schedule and the [N]-axis ``LawConfig`` leaves are arguments,
+    padded to ``Np = 2**k - 1``, the largest count with the bit length
+    k of max(N, S): Poisson flow counts of one deployment then share one
+    compiled program, and the admission's binary search over the starts
+    (``jnp.searchsorted``: as many levels as the length has bits) keeps
+    the depth it has at N; a power of two would add a level. The
+    schedule pads with inert ``pad_schedule`` entries (never admitted);
+    the config leaves repeat the last real flow's values, so an empty
+    slot, which gathers entry Np - 1 where it gathered N - 1, reads the
+    same numbers. Everything else that shapes the program is in the key:
+    topology, impairments, ``SimConfig`` and the scalar config leaves by
+    content (the topology stays concrete: ``_buffer_caps`` reads it on
+    the host), the law by value, ``bw_fn`` and the tick function
+    (``slot_step`` as the module holds it now) by identity, and the pool,
+    backend, recording, ``Np`` and the argument shapes.
+    """
+    sched = sim.sched
+    N = int(sched.start.shape[0])
+    S = int(sim.slots)
+    Np = (1 << max(N, S, 1).bit_length()) - 1
+    sched_p = jax.tree_util.tree_map(
+        np.asarray, pad_schedule(sched, Np, sim.topo.num_queues))
+    leaves, treedef = jax.tree_util.tree_flatten(sim.law_cfg)
+    is_flow = [np.ndim(x) >= 1 and np.shape(x)[0] == N for x in leaves]
+    flow = []
+    for x, f in zip(leaves, is_flow):
+        if f:
+            a = np.asarray(x)
+            flow.append(np.concatenate([a, np.repeat(a[-1:], Np - N, 0)]))
+    cfg_key = tuple("flow" if f else _digest(x)
+                    for x, f in zip(leaves, is_flow))
+    shapes = tuple((a.shape, a.dtype.str) for a in
+                   jax.tree_util.tree_leaves(sched_p) + flow)
+    key = (_digest(sim.topo), _digest(sim.impair), _digest(sim.cfg),
+           sim.law, _Ident(bw_fn), _Ident(slot_step), S, sim.backend,
+           bool(record), treedef, cfg_key, Np, shapes)
+    obs.count("slots.program_lookups")
+    with _slot_programs_lock:
+        run = _slot_programs.get(key)
+        if run is None:
+            obs.count("slots.program_misses")
+            run = _build_slot_program(sim, bw_fn, slot_step, record, treedef,
+                                      is_flow, leaves, Np)
+            _slot_programs[key] = run
+            while len(_slot_programs) > _SLOT_PROGRAMS_MAX:
+                _slot_programs.popitem(last=False)
+        else:
+            _slot_programs.move_to_end(key)
+    return run, (sched_p, flow, np.int32(N))
+
+
+def _build_slot_program(sim: SlotSim, bw_fn, step_fn, record: bool,
+                        treedef, is_flow, leaves, Np: int):
+    # close over the key's parts only: no schedule, no [N] leaves
+    base = sim._replace(sched=None, law_cfg=None)
+    static = [None if f else x for x, f in zip(leaves, is_flow)]
+
+    @jax.jit
+    def run(sched, flow, n):
+        it = iter(flow)
+        law_cfg = treedef.unflatten([next(it) if f else x
+                                     for x, f in zip(static, is_flow)])
+        simp = base._replace(sched=sched, law_cfg=law_cfg)
+        state = init_slot_state(simp)
+        audit_carry_dtypes(state)
+        final, recs = _scan_scenario(simp, state, bw_fn, None, record,
+                                     step_fn=step_fn)
+        # free slots read the caller's N, as if nothing were padded
+        return final._replace(slot_flow=jnp.where(
+            final.slot_flow == Np, n, final.slot_flow)), recs
+
+    return run
 
 
 def resume_slots(topo: Topology, sched: FlowSchedule,
@@ -1474,6 +1596,8 @@ def pad_schedule(sched: FlowSchedule, n: int, pad_queue: int) -> FlowSchedule:
     order is preserved (inf sorts last) and the admission cursor never
     reaches the padding, so padded scenarios in one batch share a flow
     count without ever admitting phantom flows. ``order`` pads with -1.
+    The padding is built on the host (numpy), so a new flow count
+    compiles no device program.
     """
     N = int(sched.start.shape[0])
     add = n - N
@@ -1483,20 +1607,20 @@ def pad_schedule(sched: FlowSchedule, n: int, pad_queue: int) -> FlowSchedule:
         return sched
 
     def cat(x, fill, dtype):
-        pad = jnp.full((add,) + tuple(x.shape[1:]), fill, dtype)
-        return jnp.concatenate([jnp.asarray(x, dtype), pad])
+        pad = np.full((add,) + tuple(x.shape[1:]), fill, dtype)
+        return np.concatenate([np.asarray(x, dtype), pad])
 
     return FlowSchedule(
-        path=cat(sched.path, pad_queue, jnp.int32),
-        tf_steps=cat(sched.tf_steps, 1, jnp.int32),
-        rtt_steps=cat(sched.rtt_steps, 1, jnp.int32),
-        tau=cat(sched.tau, 20e-6, jnp.float32),
-        nic_rate=cat(sched.nic_rate, 1e9, jnp.float32),
-        size=cat(sched.size, jnp.inf, jnp.float32),
-        start=cat(sched.start, jnp.inf, jnp.float32),
-        stop=cat(sched.stop, jnp.inf, jnp.float32),
-        weight=cat(sched.weight, 1.0, jnp.float32),
-        order=cat(sched.order, -1, jnp.int32),
+        path=cat(sched.path, pad_queue, np.int32),
+        tf_steps=cat(sched.tf_steps, 1, np.int32),
+        rtt_steps=cat(sched.rtt_steps, 1, np.int32),
+        tau=cat(sched.tau, 20e-6, np.float32),
+        nic_rate=cat(sched.nic_rate, 1e9, np.float32),
+        size=cat(sched.size, np.inf, np.float32),
+        start=cat(sched.start, np.inf, np.float32),
+        stop=cat(sched.stop, np.inf, np.float32),
+        weight=cat(sched.weight, 1.0, np.float32),
+        order=cat(sched.order, -1, np.int32),
     )
 
 

@@ -46,13 +46,17 @@ def make_schedule(flows: Flows) -> FlowSchedule:
     with obs.span("schedule.build", flows=int(flows.start.shape[0])):
         start = np.asarray(flows.start)
         perm = np.argsort(start, kind="stable")
-        idx = jnp.asarray(perm.astype(np.int32))
+
+        def take(x):    # on the host: a device gather compiles per N
+            return jnp.asarray(np.asarray(x)[perm])
+
         return FlowSchedule(
-            path=flows.path[idx], tf_steps=flows.tf_steps[idx],
-            rtt_steps=flows.rtt_steps[idx], tau=flows.tau[idx],
-            nic_rate=flows.nic_rate[idx], size=flows.size[idx],
-            start=flows.start[idx], stop=flows.stop[idx],
-            weight=flows.weight[idx], order=idx)
+            path=take(flows.path), tf_steps=take(flows.tf_steps),
+            rtt_steps=take(flows.rtt_steps), tau=take(flows.tau),
+            nic_rate=take(flows.nic_rate), size=take(flows.size),
+            start=take(flows.start), stop=take(flows.stop),
+            weight=take(flows.weight),
+            order=jnp.asarray(perm.astype(np.int32)))
 
 
 def schedule_as_flows(sched: FlowSchedule) -> Flows:

@@ -24,6 +24,8 @@ Spans and counters (PERF.md lists the metric each is for):
 
   slots.calls            entry calls (simulate_slots, simulate_slots_sharded)
   slots.ticks            ticks stepped
+  slots.program_lookups  lookups of the whole-trace slot program cache
+  slots.program_misses   of them, those that built a new program
   chunk.segments         segment programs called by a chunk loop
   halo.fallback_ticks    sharded ticks that took the full-gather fallback
 """

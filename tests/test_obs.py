@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.core import (SimConfig, default_law_config, make_flows_single,
                         make_schedule, obs, schedule_as_flows, simulate_slots,
                         single_bottleneck)
+from repro.core import fluid
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -96,9 +97,12 @@ def _small(steps=700):
 
 def test_slot_engine_counts_calls_ticks_and_segments():
     topo, sched, lcfg, cfg = _small()
+    fluid._slot_programs.clear()                  # a cold program cache
     whole, _ = simulate_slots(topo, sched, "powertcp", 8, lcfg, cfg,
                               record=False)
-    assert obs.counters() == {"slots.calls": 1, "slots.ticks": cfg.steps}
+    assert obs.counters() == {"slots.calls": 1, "slots.ticks": cfg.steps,
+                              "slots.program_lookups": 1,
+                              "slots.program_misses": 1}
     obs.reset()
     chunked, _ = simulate_slots(topo, sched, "powertcp", 8, lcfg, cfg,
                                 record=False, chunk=8)
