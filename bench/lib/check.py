@@ -1,7 +1,8 @@
 """Deciding ``correct``: the window's FCT vectors against the plain
 reference.
 
-A sample of the window's points, drawn from the run's seed, is run again
+A sample of the window's points, drawn from the run's seed (``sample``:
+one job, and of a grid job one point of each law), is run again
 through ``reference.simulate`` at the timed size, and the program's FCT
 vector (schedule order) is compared flow by flow, each number taken per
 law as ``<number>.<law>`` (the worst over that law's sampled points):
@@ -58,10 +59,19 @@ def pool_peak(fl: reference.Flows, fct, dt: float) -> int:
 
 
 def sample(results, rng):
-    """The points to check: every point of one job of the window, drawn
-    from ``rng``."""
+    """The points to check: one job of the window, drawn from ``rng``,
+    and of a job of several points (a grid), one point of each law,
+    drawn from ``rng`` after it, in the order of the laws' first
+    points."""
     job, fcts = results[int(rng.integers(len(results)))]
-    return list(zip(job["points"], fcts))
+    pairs = list(zip(job["points"], fcts))
+    if len(pairs) == 1:
+        return pairs
+    out = []
+    for law in dict.fromkeys(p["law"] for p, _ in pairs):
+        mine = [pf for pf in pairs if pf[0]["law"] == law]
+        out.append(mine[int(rng.integers(len(mine)))])
+    return out
 
 
 def reference_run(cell, desc, point, dtype="float32"):

@@ -294,7 +294,7 @@ def report(tr: dict, prog: dict) -> dict:
     if win is None or not tr["ops"]:
         return {}
     lo, hi = win
-    dev = trace.busiest(tr, lo, hi)
+    dev = trace.busiest(trace.busy(tr, lo, hi))
     calls = _calls(prog, lo, hi)
     ticks = sum(int(a.get("ticks", 0)) for *_, a in calls)
     jobs = _jobs(tr, lo, hi)

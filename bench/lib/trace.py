@@ -17,6 +17,8 @@ import gzip
 import os
 import re
 
+import numpy as np
+
 COLLECTIVE = re.compile(r"all-to-all|all-reduce|all-gather|collective-permute"
                         r"|reduce-scatter|psum", re.I)
 
@@ -56,23 +58,33 @@ def load(path: str) -> dict:
 
 
 def union(intervals):
-    """Sorted, disjoint union of (start, end) pairs."""
-    out = []
-    for s, e in sorted(intervals):
-        if out and s <= out[-1][1]:
-            out[-1][1] = max(out[-1][1], e)
-        else:
-            out.append([s, e])
-    return out
+    """Sorted, disjoint union of (start, end) pairs, as an [n, 2] array
+    (a window traces tens of millions of ops)."""
+    if not isinstance(intervals, np.ndarray):
+        intervals = list(intervals)
+    a = np.asarray(intervals, np.float64).reshape(-1, 2)
+    if not len(a):
+        return a
+    a = a[np.argsort(a[:, 0], kind="stable")]
+    end = np.maximum.accumulate(a[:, 1])
+    first = np.r_[True, a[1:, 0] > end[:-1]]
+    return np.stack([a[first, 0], end[np.r_[first[1:], True]]], 1)
 
 
 def clip(intervals, lo, hi):
-    return [(max(s, lo), min(e, hi)) for s, e in intervals
-            if min(e, hi) > max(s, lo)]
+    a = np.asarray(intervals, np.float64).reshape(-1, 2).clip(lo, hi)
+    return a[a[:, 1] > a[:, 0]]
 
 
 def length(intervals) -> float:
     return float(sum(e - s for s, e in intervals))
+
+
+def op_intervals(events):
+    """The ``(start, end)`` of ``(name, start, end)`` events, as an
+    [n, 2] array."""
+    return np.fromiter((t for _, s, e in events for t in (s, e)),
+                       np.float64, 2 * len(events)).reshape(-1, 2)
 
 
 def subtract(a, b):
@@ -102,38 +114,58 @@ def window(tr: dict):
             max(e for _, _, e in tr["spans"]))
 
 
+def loop_ticks(tr: dict, device: str, lo, hi) -> int:
+    """Iterations of the tick loop that ``device`` ran inside [lo, hi]:
+    the median count of the op names that ran there more than once (the
+    loop body's ops run once a tick; a tick cut in two counts once)."""
+    counts = {}
+    for name, s, e in tr["ops"][device]:
+        if s >= lo and e <= hi:
+            counts[name] = counts.get(name, 0) + 1
+    many = sorted(c for c in counts.values() if c > 1)
+    return many[(len(many) - 1) // 2] if many else 0
+
+
 def busy(tr: dict, lo, hi) -> dict:
     """Busy ns of each device inside [lo, hi]: the union of its ops."""
-    return {d: length(clip(union((s, e) for _, s, e in ev), lo, hi))
-            for d, ev in tr["ops"].items()}
+    out = {}
+    for d, ev in tr["ops"].items():
+        on = clip(union(op_intervals(ev)), lo, hi)
+        out[d] = float((on[:, 1] - on[:, 0]).sum())
+    return out
 
 
-def busiest(tr: dict, lo, hi):
-    b = busy(tr, lo, hi)
-    return max(b, key=b.get) if b else None
+def busiest(busy_ns: dict):
+    """The device of ``busy``'s result that was busy longest."""
+    return max(busy_ns, key=busy_ns.get) if busy_ns else None
 
 
 def idle_gaps(tr: dict, device: str, lo, hi):
     """(span name, ns) of each gap between ops on ``device`` inside
     [lo, hi], named by the harness span its midpoint falls in."""
-    on = clip(union((s, e) for _, s, e in tr["ops"][device]), lo, hi)
-    gaps = subtract([(lo, hi)], on)
-    out = []
-    for s, e in gaps:
-        mid = 0.5 * (s + e)
-        names = [n for n, a, b in tr["spans"] if a <= mid < b]
-        out.append((names[-1] if names else "harness", e - s))
-    return out
+    on = clip(union(op_intervals(tr["ops"][device])), lo, hi)
+    gs, ge = np.r_[lo, on[:, 1]], np.r_[on[:, 0], hi]
+    keep = ge > gs
+    gs, ge = gs[keep], ge[keep]
+    mid = 0.5 * (gs + ge)
+    name = np.full(len(mid), -1)
+    for i, (_, a, b) in enumerate(tr["spans"]):
+        name[(a <= mid) & (mid < b)] = i
+    names = [n for n, _, _ in tr["spans"]] + ["harness"]
+    return [(names[i], g) for i, g in zip(name.tolist(), (ge - gs).tolist())]
 
 
 def top_ops(tr: dict, device: str, lo, hi, n=10):
     """The ``n`` op names with the most device time inside [lo, hi]."""
-    tot = {}
+    per = {}
     for name, s, e in tr["ops"][device]:
         d = min(e, hi) - max(s, lo)
         if d > 0:
-            key = re.sub(r"\.\d+$", "", name)
-            tot[key] = tot.get(key, 0) + d
+            per[name] = per.get(name, 0) + d
+    tot = {}
+    for name, d in per.items():
+        key = re.sub(r"\.\d+$", "", name)
+        tot[key] = tot.get(key, 0) + d
     return sorted(tot.items(), key=lambda kv: -kv[1])[:n]
 
 
@@ -144,7 +176,7 @@ def exposed_collective_share(tr: dict, lo, hi):
     shares = []
     for d, ev in tr["ops"].items():
         coll = union((s, e) for n, s, e in ev if COLLECTIVE.search(n))
-        if not coll:
+        if not len(coll):
             continue
         comp = union((s, e) for n, s, e in ev if not COLLECTIVE.search(n))
         shares.append(length(subtract(clip(coll, lo, hi), comp)) / (hi - lo))

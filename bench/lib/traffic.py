@@ -114,6 +114,14 @@ def scenario(mix: dict, fab: dict, seed: int) -> List[Dict]:
     return out
 
 
+def at_load(mix: dict, load: float) -> dict:
+    """``mix`` with ``load`` in place of its ``poisson_websearch``
+    components' load (a load-sweep grid's point)."""
+    comps = [dict(c, load=load) if c["generator"] == "poisson_websearch"
+             else c for c in mix["components"]]
+    return dict(mix, components=comps)
+
+
 def scenario_seeds(seed: int, job: int, n: int) -> np.ndarray:
     """The ``n`` traffic seeds of job ``job`` of the stream a run's
     ``--seed`` gives (any non-negative integer, also beyond 32 bits).
