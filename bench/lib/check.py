@@ -82,7 +82,7 @@ def reference_run(cell, desc, point, dtype="float32"):
     links = reference.build_links(desc, cfg.get("impairments"))
     sim = reference.Sim(point["law"], s["dt"], s["steps"], s["hist"],
                         s["update_period"], desc.n_switches,
-                        desc.switch_buffer, desc.dt_alpha, dtype)
+                        desc.switch_buffer, desc.dt_alpha, dtype, cell.dir)
     fct = reference.simulate(sim, reference.pad(fl, desc.Q), links,
                              dict(cfg["law_config"]))
     return fl, np.asarray(fct)[:fl.tau.shape[0]]

@@ -7,6 +7,11 @@ traffic generator needs, and for the plain reference the queues
 (capacity, buffer, owning switch, link class) and each flow's path with
 its per-hop forward delays and round-trip time.
 
+A kind may also give ``phase_s``, [Q] seconds, NaN where it declares
+none: each queue's phase, which a link process with ``"t0_s":
+"fabric"`` takes (``reference.build_links``), as a circuit's slot in a
+reconfigurable fabric's week.
+
 Queue numbering and ECMP choice follow the published fabrics and the
 hash the program documents (a splitmix64 finalizer over seed, source,
 destination and flow index, taken modulo the pair's equal-cost path
@@ -18,6 +23,8 @@ from __future__ import annotations
 import os
 
 import numpy as np
+
+from .spec import BENCH, load_module
 
 GBPS = 1e9 / 8.0
 US = 1e-6
@@ -39,11 +46,10 @@ def ecmp_hash(src, dst, flow_id, seed) -> np.ndarray:
     return h
 
 
-def describe(fabric_cfg: dict):
+def describe(fabric_cfg: dict, bench_dir: str = BENCH):
     """The description of a configuration's ``fabric`` group:
-    ``bench/fabrics/<kind>.py``'s ``Fabric``. A later fabric kind is
-    added as a file alone."""
-    from .spec import BENCH, load_module
-    mod = load_module(os.path.join(BENCH, "fabrics",
+    ``<bench_dir>/fabrics/<kind>.py``'s ``Fabric`` (``bench_dir`` the
+    cell's). A later fabric kind is added as a file alone."""
+    mod = load_module(os.path.join(bench_dir, "fabrics",
                                    fabric_cfg["kind"] + ".py"))
     return mod.Fabric(fabric_cfg)

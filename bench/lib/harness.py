@@ -234,10 +234,10 @@ def run(cell, seed: int, seconds: float, traced: bool, root: str,
 
     from . import fabrics, program
     cfg = cell.config
-    desc = fabrics.describe(cfg["fabric"])
+    desc = fabrics.describe(cfg["fabric"], cell.dir)
     fab = dict(n_hosts=desc.n_hosts, group=desc.group,
                load_capacity=desc.load_capacity)
-    dep = program.deploy(cfg)
+    dep = program.deploy(cfg, cell.dir)
     entry = cell.load_module("entries",
                              cell.traffic.get("entry", cfg["entry"]))
     first = make_job(cell, fab, seed, 0)

@@ -4,6 +4,18 @@ Everything that touches the program's public API to build a deployment
 lives here, in ``bench/deploy/<fabric kind>.py`` and in
 ``bench/entries/``: the fabric, its link processes, the law constants
 and the routing of a scenario's flow arrays.
+
+The ``impairments`` group (its schema in ``reference.py``) becomes the
+program's ``ImpairmentParams``: each rule one ``LinkProcess`` for its
+(src tier, dst tier) class, compiled by ``fabric_impairments``, with
+every field the configuration states (``bw_hi_gbps``, ``bw_lo_gbps``,
+``period_s``, ``up_s``, ``t0_s``, ``loss``, ``random_loss``,
+``jitter_s``, ``seed``). A rule with ``"t0_s": "fabric"`` takes each
+queue's phase from ``phases(f, fabric)`` of ``bench/deploy/<kind>.py``
+(``f`` the ``fabric`` group, ``fabric`` the program's ``Fabric`` graph;
+[Q] seconds in the program's queue order, NaN where the kind declares
+none), put into the ``t0`` leaf; a kind without ``phases`` is an
+error.
 """
 from __future__ import annotations
 
@@ -13,6 +25,7 @@ from typing import NamedTuple
 import numpy as np
 
 import jax
+import jax.numpy as jnp
 
 from repro.core import (GBPS, US, LinkProcess, SimConfig, fabric_impairments,
                         make_schedule)
@@ -38,24 +51,55 @@ def _fabric_kwargs(f: dict) -> dict:
 
 
 def _process(p: dict) -> LinkProcess:
+    t0 = p.get("t0_s", 0.0)
     return LinkProcess(kind=p["kind"],
+                       bw_hi=p.get("bw_hi_gbps", 0.0) * GBPS,
                        bw_lo=p.get("bw_lo_gbps", 0.0) * GBPS,
-                       period=p.get("period_s", 0.0), loss=p.get("loss", 0.0),
+                       period=p.get("period_s", 0.0), up=p.get("up_s", 0.0),
+                       t0=0.0 if t0 == "fabric" else t0,
+                       loss=p.get("loss", 0.0),
                        random_loss=p.get("random_loss", False),
-                       seed=p.get("seed", 0))
+                       jitter=p.get("jitter_s", 0.0), seed=p.get("seed", 0))
 
 
-def deploy(cfg: dict) -> Deployment:
+def _impairments(imp: dict, kind, f: dict, routes):
+    """The ``ImpairmentParams`` of an ``impairments`` group."""
+    fab = getattr(routes, "fabric", routes)
+    by_class = {tuple(_TIERS[t] for t in r["links"]): r
+                for r in imp.get("rules", [])}
+    default = imp.get("default")
+    impair = fabric_impairments(
+        routes, rules={k: _process(r) for k, r in by_class.items()},
+        default=None if default is None else _process(default))
+    ql = fab.queued_links()
+    procs = [by_class.get((int(fab.tier[fab.link_src[l]]),
+                           int(fab.tier[fab.link_dst[l]])), default)
+             for l in ql]
+    mine = np.array([p is not None and p.get("t0_s") == "fabric"
+                     for p in procs])
+    if not mine.any():
+        return impair
+    if not hasattr(kind, "phases"):
+        raise ValueError("the fabric kind declares no phases")
+    phase = np.asarray(kind.phases(f, fab), np.float64)
+    if np.isnan(phase[mine]).any():
+        raise ValueError("the fabric kind declares no phase for queues "
+                         f"{np.flatnonzero(mine & np.isnan(phase))}")
+    t0 = np.where(mine, phase, np.asarray(impair.t0))
+    return impair._replace(t0=jnp.asarray(t0, jnp.float32))
+
+
+def deploy(cfg: dict, bench_dir: str = BENCH) -> Deployment:
+    """The program's deployment of a configuration; the fabric kind's
+    constructor is ``<bench_dir>/deploy/<kind>.py`` (``bench_dir`` the
+    cell's)."""
     f = cfg["fabric"]
-    kind = load_module(os.path.join(BENCH, "deploy", f["kind"] + ".py"))
+    kind = load_module(os.path.join(bench_dir, "deploy", f["kind"] + ".py"))
     fab, routes = kind.build(f, _fabric_kwargs(f))
     imp = cfg.get("impairments")
     impair = None
     if imp:
-        rules = {tuple(_TIERS[t] for t in r["links"]): _process(r)
-                 for r in imp.get("rules", [])}
-        default = _process(imp["default"]) if "default" in imp else None
-        impair = fabric_impairments(routes, rules=rules, default=default)
+        impair = _impairments(imp, kind, f, routes)
     s = cfg["sim"]
     sim = SimConfig(dt=s["dt"], steps=s["steps"], hist=s["hist"],
                     update_period=s["update_period"])

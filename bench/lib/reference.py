@@ -16,14 +16,41 @@ program documents (DESIGN.md sections 9, 12, 14 and 17 of the program):
   telemetry   each hop's queue, queue gradient and egress rate as they
               were rtt_i - tf_ij ticks ago, the window of one measured
               RTT ago
+  links       each queue's capacity and keep fraction at the tick's time,
+              from its link process (``build_links``, ``link_state``)
   laws        each a file ``bench/laws/<law>.py`` (PowerTCP with INT,
-              HPCC, TIMELY as published), on a timer of ``update_period``
+              HPCC, TIMELY, reTCP as published), on a timer of
+              ``update_period``; a law sees the run's ``Links`` as
+              ``constants["links"]``
   progress    remaining_i -= lam_i * keep(path_i) * dt; the FCT is the
               tick's time plus half the base RTT minus the start
 
 ``dtype`` sets the precision of the flow and queue state and of its
 arithmetic; the clock (tick times, timers, starts, FCTs) stays float32.
 ``jnp.bfloat16`` gives the control that ``correct`` must refuse.
+
+Link processes come from the configuration's ``impairments`` group:
+``{"rules": [...], "default": {...}}``, each rule naming the class it
+applies to as ``"links": [<src tier>, <dst tier>]`` (a later rule of a
+class wins; queues of no rule's class take ``default``, else no
+process). A process has a ``kind``:
+
+  const       the link's own capacity
+  oscillate   a triangle wave between ``bw_lo_gbps`` (required) and the
+              link's capacity over ``period_s``
+  schedule    a circuit's day/night square wave: ``bw_hi_gbps`` (default
+              the link's capacity) for the first ``up_s`` of every
+              ``period_s``, ``bw_lo_gbps`` (required) for the rest
+
+and may add ``t0_s``, the phase (default 0): ``ph = mod(t - t0 +
+nudge, period)``, the triangle's position or the circuit up while
+``0 <= ph < up_s`` (DESIGN.md section 17). ``"t0_s": "fabric"`` gives
+each queue of the rule its phase from the fabric kind's description,
+``phase_s`` ([Q] seconds, NaN where the kind declares none); asking for
+it where there is none is an error. ``loss`` (a fraction), with
+``random_loss`` drawn per ``period_s``-long epoch from ``seed``, is
+kept on any kind. The reference models no jitter and refuses a process
+that asks for it.
 """
 from __future__ import annotations
 
@@ -37,6 +64,7 @@ import jax
 import jax.numpy as jnp
 
 from . import fabrics
+from .spec import BENCH, load_module
 
 MTU = 1000.0
 _NUDGE = 1e-7          # keeps an epoch edge off the tick grid
@@ -55,15 +83,18 @@ class Flows(NamedTuple):
 
 
 class Links(NamedTuple):
-    bw: jnp.ndarray        # [Q] f32 bytes/s
+    bw: jnp.ndarray        # [Q] f32 bytes/s (the wave's peak, the day's)
     buf: jnp.ndarray       # [Q] f32 bytes
     sw: jnp.ndarray        # [Q] int32 switch that owns the queue
     osc: jnp.ndarray       # [Q] bool: capacity follows a triangle wave
-    bw_lo: jnp.ndarray     # [Q] f32 bytes/s at the wave's trough
-    period: jnp.ndarray    # [Q] f32 s (wave period, or loss epoch)
+    bw_lo: jnp.ndarray     # [Q] f32 bytes/s at the trough, the night's
+    period: jnp.ndarray    # [Q] f32 s (wave period or week, loss epoch)
     loss: jnp.ndarray      # [Q] f32 loss fraction (or its cap if random)
     loss_random: jnp.ndarray   # [Q] bool
     seed: jnp.ndarray      # [Q] uint32
+    sched: jnp.ndarray     # [Q] bool: capacity follows a day/night wave
+    up: jnp.ndarray        # [Q] f32 s of each period at bw (the day)
+    t0: jnp.ndarray        # [Q] f32 s phase
 
 
 def build_flows(desc, groups, dt: float, horizon_s: float) -> Flows:
@@ -111,12 +142,16 @@ def pad(fl: Flows, n_queues: int, multiple: int = 512) -> Flows:
 
 def build_links(desc, impair: dict | None) -> Links:
     """Per-queue capacities, buffers and link processes from the
-    configuration's ``fabric`` and ``impairments`` groups."""
+    configuration's ``fabric`` and ``impairments`` groups (the schema in
+    the module docstring)."""
     Q = desc.Q
     bw = desc.bandwidth.astype(np.float32)
     osc = np.zeros(Q, bool)
+    sched = np.zeros(Q, bool)
     bw_lo = bw.copy()
     period = np.zeros(Q, np.float32)
+    up = np.zeros(Q, np.float32)
+    t0 = np.zeros(Q, np.float32)
     loss = np.zeros(Q, np.float32)
     loss_random = np.zeros(Q, bool)
     seed = np.zeros(Q, np.uint32)
@@ -130,12 +165,21 @@ def build_links(desc, impair: dict | None) -> Links:
                 proc = r
         if proc is None:
             continue
+        if proc.get("jitter_s", 0.0):
+            raise ValueError("the reference has no jitter")
         if proc["kind"] == "oscillate":
             osc[q] = True
             bw_lo[q] = np.float32(proc["bw_lo_gbps"] * fabrics.GBPS)
+        elif proc["kind"] == "schedule":
+            sched[q] = True
+            bw_lo[q] = np.float32(proc["bw_lo_gbps"] * fabrics.GBPS)
+            if "bw_hi_gbps" in proc:
+                bw[q] = np.float32(proc["bw_hi_gbps"] * fabrics.GBPS)
+            up[q] = proc["up_s"]
         elif proc["kind"] != "const":
             raise ValueError(f"the reference has no {proc['kind']!r} "
                              f"link process")
+        t0[q] = _phase(desc, proc.get("t0_s", 0.0), q)
         period[q] = proc.get("period_s", 0.0)
         loss[q] = proc.get("loss", 0.0)
         loss_random[q] = proc.get("random_loss", False)
@@ -145,7 +189,19 @@ def build_links(desc, impair: dict | None) -> Links:
                  jnp.asarray(desc.switch_of_queue, jnp.int32),
                  jnp.asarray(osc), jnp.asarray(bw_lo), jnp.asarray(period),
                  jnp.asarray(loss), jnp.asarray(loss_random),
-                 jnp.asarray(seed))
+                 jnp.asarray(seed), jnp.asarray(sched), jnp.asarray(up),
+                 jnp.asarray(t0))
+
+
+def _phase(desc, t0, q: int) -> float:
+    """A rule's ``t0_s`` for queue ``q``: the number, or with
+    ``"fabric"`` the fabric kind's ``phase_s[q]``."""
+    if t0 != "fabric":
+        return t0
+    phase = getattr(desc, "phase_s", None)
+    if phase is None or np.isnan(phase[q]):
+        raise ValueError(f"the fabric kind declares no phase for queue {q}")
+    return phase[q]
 
 
 def _mix32(x):
@@ -156,12 +212,19 @@ def _mix32(x):
     return x ^ (x >> 16)
 
 
-def _link_state(t_sec, L: Links):
+def circuit_up(t_sec, L: Links):
+    """[Q] bool: is each scheduled link in its day at ``t_sec``?"""
+    ph = jnp.mod(t_sec - L.t0 + _NUDGE, L.period)
+    return L.sched & (ph >= 0.0) & (ph < L.up)
+
+
+def link_state(t_sec, L: Links):
     """(capacity [Q], keep fraction [Q]) of every link at ``t_sec``."""
-    ph = jnp.mod(t_sec + _NUDGE, L.period)
+    ph = jnp.mod(t_sec - L.t0 + _NUDGE, L.period)
     tri = 1.0 - jnp.abs(2.0 * (ph / L.period) - 1.0)
     bw = jnp.where(L.osc, L.bw_lo + (L.bw - L.bw_lo) * tri, L.bw)
-    epoch = jnp.floor((t_sec + _NUDGE) / jnp.maximum(L.period, 1e-6))
+    bw = jnp.where(L.sched & ~circuit_up(t_sec, L), L.bw_lo, bw)
+    epoch = jnp.floor((t_sec - L.t0 + _NUDGE) / jnp.maximum(L.period, 1e-6))
     qid = jnp.arange(L.bw.shape[0], dtype=jnp.uint32)
     h = _mix32(L.seed ^ jnp.uint32(_SALT_LOSS))
     h = _mix32(h ^ (qid * jnp.uint32(0x9E3779B9)))
@@ -179,13 +242,14 @@ def smooth(prev, new, dt_obs, tau):
 
 
 @lru_cache(maxsize=None)
-def law_rule(name: str):
-    """The control law ``name``: ``bench/laws/<name>.py``, with
+def law_rule(name: str, bench_dir: str = BENCH):
+    """The control law ``name``: ``<bench_dir>/laws/<name>.py``, with
     ``init(flows, constants, dtype) -> state`` and
     ``update(state, obs, w, cap, due, flows, constants, t) -> (state, w,
-    cap)``. A later law is added as a file alone."""
-    from .spec import BENCH, load_module
-    return load_module(os.path.join(BENCH, "laws", name + ".py"))
+    cap)``; ``constants`` holds the configuration's ``law_config``,
+    ``beta`` and the run's ``Links`` as ``links``. A later law is added
+    as a file alone."""
+    return load_module(os.path.join(bench_dir, "laws", name + ".py"))
 
 
 class Sim(NamedTuple):
@@ -199,11 +263,13 @@ class Sim(NamedTuple):
     switch_buffer: float
     dt_alpha: float
     dtype: str = "float32"
+    bench_dir: str = BENCH       # where ``law_rule`` finds the law
 
 
-def law_constants(law_cfg: dict, fl: Flows, ft):
+def law_constants(law_cfg: dict, fl: Flows, L: Links, ft):
     c = {k: v for k, v in law_cfg.items() if k != "expected_flows"}
     c["beta"] = (fl.nic * fl.tau / law_cfg["expected_flows"]).astype(ft)
+    c["links"] = L
     return c
 
 
@@ -215,8 +281,8 @@ def simulate(sim: Sim, fl: Flows, L: Links, law_cfg: dict):
     F, Hh = fl.path.shape
     Q = L.bw.shape[0]
     D, dt = sim.hist, sim.dt
-    rule = law_rule(sim.law)
-    c = law_constants(law_cfg, fl, ft)
+    rule = law_rule(sim.law, sim.bench_dir)
+    c = law_constants(law_cfg, fl, L, ft)
     valid = fl.path < Q
     fidx = jnp.arange(F)
     nic = fl.nic.astype(ft)
@@ -230,7 +296,7 @@ def simulate(sim: Sim, fl: Flows, L: Links, law_cfg: dict):
         t = s["t"]
         t_sec = t.astype(jnp.float32) * jnp.float32(dt)
         ptr = t % D
-        bw_l, keep_l = _link_state(t_sec, L)
+        bw_l, keep_l = link_state(t_sec, L)
         bw = jnp.concatenate([bw_l.astype(ft), sentinel])
         keep = jnp.concatenate([keep_l.astype(ft), jnp.ones((1,), ft)])
         started = t_sec >= fl.start

@@ -37,10 +37,10 @@ def readings(cell, seeds, control: bool, require_tpu=True, out=sys.stdout):
         harness.devices(cell.chips)
     harness.window_cache(harness.use_compile_cache(ROOT))
     cfg = cell.config
-    desc = fabrics.describe(cfg["fabric"])
+    desc = fabrics.describe(cfg["fabric"], cell.dir)
     fab = dict(n_hosts=desc.n_hosts, group=desc.group,
                load_capacity=desc.load_capacity)
-    dep = program.deploy(cfg)
+    dep = program.deploy(cfg, cell.dir)
     entry = cell.load_module("entries", cell.traffic.get("entry",
                                                          cfg["entry"]))
     none = lambda _: contextlib.nullcontext()

@@ -11,7 +11,8 @@ import sys
 import numpy as np
 import pytest
 
-from bench.lib import check, fabrics, harness, spec, traffic
+from bench.lib import (check, fabrics, harness, program, reference,
+                       spec, traffic)
 from bench.tests import readings, small
 
 ROOT = small.ROOT
@@ -124,6 +125,78 @@ def test_one_point_jobs_are_unchanged(name, j):
     assert [sorted(p) for p in job["points"]] == [["groups", "law",
                                                     "scenario"]]
     assert _job_hash(job) == PARENT_JOBS[(name, j)]
+
+
+def _leaves_hash(nt, fields) -> str:
+    h = hashlib.sha256()
+    for k in fields:
+        a = np.asarray(getattr(nt, k))
+        h.update(k.encode())
+        h.update(str(a.dtype).encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+# the reference's Links (the fields it had before circuit schedules) and
+# the program's ImpairmentParams of each configuration, and the
+# reference FCTs of the first point of each law of job 0 of seed
+# 2**40 + 7 at each cell's CPU cut, as the harness built them before
+# circuit schedules
+PARENT_LINK_FIELDS = ("bw", "buf", "sw", "osc", "bw_lo", "period", "loss",
+                      "loss_random", "seed")
+LS_LINKS = "a74cc1cf23ac3763bd68379f0c75fe9898b5724b673c1a4549044036d823d582"
+PARENT_DEPLOYMENTS = {
+    "ls256-ws60": (LS_LINKS, None),
+    "ft16-degraded": (
+        "06b081042c0eb8e41e04c293d4fa7f6d2936c07ed68cb22ed8d614b1ca12a6c1",
+        "47f136b2cf022aabcfb299f7b241e1c044facc179f04784c581fdc0a2f25a343"),
+    "ls256-fig7-sweep": (LS_LINKS, None),
+}
+PARENT_REFERENCE_FCTS = {
+    "ls256-ws60": [
+        ("powertcp", "06593e04e1406867e099c4e49dadb787"
+                     "f891d13a2ed74f24059c6388d8d7536e")],
+    "ft16-degraded": [
+        ("powertcp", "3e93a047e9241d743d9f3976a99a2c36"
+                     "c78cc21ef42c22d8e577f001a503bf54")],
+    "ls256-fig7-sweep": [
+        ("powertcp", "ad2a2947327e5744f893d9c84027b713"
+                     "e8b3024685002db71370cd057ef862e2"),
+        ("hpcc", "b62c1233b2151611ced89385c167611f"
+                 "09abee51c3e72444836d98991a4ace29"),
+        ("timely", "fb9c8759e6ff93b84bc052379773c2be"
+                   "cf8c98cafab4af0f303c5719a973b544")],
+}
+
+
+@pytest.mark.parametrize("name", list(PARENT_DEPLOYMENTS))
+def test_deployments_are_unchanged(name):
+    c = spec.Cell.named(ROOT, name)
+    links = reference.build_links(fabrics.describe(c.config["fabric"]),
+                                  c.config.get("impairments"))
+    impair = program.deploy(c.config).impair
+    want_links, want_impair = PARENT_DEPLOYMENTS[name]
+    assert _leaves_hash(links, PARENT_LINK_FIELDS) == want_links
+    assert not np.asarray(links.sched).any()
+    assert not np.asarray(links.up).any() and not np.asarray(links.t0).any()
+    assert (None if impair is None
+            else _leaves_hash(impair, impair._fields)) == want_impair
+
+
+@pytest.mark.parametrize("name", list(PARENT_REFERENCE_FCTS))
+def test_reference_fcts_are_unchanged(name):
+    c = small.cell(name)
+    desc = fabrics.describe(c.config["fabric"])
+    job = harness.make_job(c, _fab(c), 2**40 + 7, 0)
+    firsts = {}
+    for p in job["points"]:
+        firsts.setdefault(p["law"], p)
+    got = []
+    for law, p in firsts.items():
+        _, fct = check.reference_run(c, desc, p)
+        got.append((law, hashlib.sha256(
+            np.asarray(fct, np.float32).tobytes()).hexdigest()))
+    assert got == PARENT_REFERENCE_FCTS[name]
 
 
 def test_sample_draws_one_point_per_law_of_a_grid_job():

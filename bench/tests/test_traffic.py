@@ -117,7 +117,7 @@ def test_reference_links_match_the_programs_topology_and_impairments():
         kind="oscillate", bw_lo=40e9 / 8, period=5e-4, seed=7)},
         default=netem(loss=0.002, seed=13))
     for t in (0.0, 1.3e-4, 2.51e-4, 7.77e-4):
-        bw, keep = reference._link_state(np.float32(t), L)
+        bw, keep = reference.link_state(np.float32(t), L)
         np.testing.assert_allclose(np.asarray(bw),
                                    np.asarray(link_bw_at(t, p)), rtol=1e-6)
         assert np.array_equal(np.asarray(keep),
